@@ -8,7 +8,9 @@
   lm     LM substrate step times (reduced configs)
   roofline  §Roofline terms from dry-run artifacts (if present)
 
-Multi-device sections run in subprocesses with forced host device counts.
+Every section runs in a subprocess of its own (one process per chip); on the
+CPU it gets a forced host device count, and on a TPU a section fails when
+fewer chips exist than it asks for.
 ``REPRO_BENCH_SCALE`` scales the Table-I suite (default 0.1);
 ``REPRO_BENCH_FAST=1`` (or ``--quick``) runs a reduced set for CI-style smoke
 runs.
@@ -56,25 +58,20 @@ class _Tee(io.TextIOBase):
 def provenance() -> dict:
     """Environment fingerprint stored under the ``_provenance`` key of the
     bench JSON: enough to explain a cross-run timing shift (different jax,
-    different device fleet, different commit) without gating on it. Every
-    field degrades to a placeholder rather than failing the bench run."""
-    prov = {"timestamp_utc": "", "jax_version": "", "platform": "",
-            "device_kind": "", "device_count": 0, "git_sha": ""}
+    different device fleet, different commit) without gating on it. Called
+    after every section's process has exited, so querying the devices here
+    takes no chip from them; a failed device query fails the run."""
     import datetime
 
-    prov["timestamp_utc"] = datetime.datetime.now(
-        datetime.timezone.utc).isoformat(timespec="seconds")
-    try:
-        import jax
+    import jax
 
-        prov["jax_version"] = jax.__version__
-        devs = jax.devices()
-        prov["platform"] = devs[0].platform if devs else ""
-        prov["device_kind"] = devs[0].device_kind if devs else ""
-        prov["device_count"] = len(devs)
-    except Exception:
-        pass
-    try:
+    devs = jax.devices()
+    prov = {"timestamp_utc": datetime.datetime.now(
+                datetime.timezone.utc).isoformat(timespec="seconds"),
+            "jax_version": jax.__version__, "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind, "device_count": len(devs),
+            "git_sha": ""}
+    try:  # a checkout without git history has no sha to record
         import subprocess
 
         prov["git_sha"] = subprocess.run(
@@ -110,11 +107,10 @@ def main() -> None:
         scale = os.environ.get("REPRO_BENCH_SCALE", "0.05" if fast else "0.1")
         env = {"REPRO_BENCH_SCALE": scale}
 
-        # plan-level analysis (no devices)
-        from benchmarks import bench_comm_volume, bench_interconnect_model
-
-        bench_comm_volume.main()
-        bench_interconnect_model.main()
+        # plan-level analysis (one device)
+        print(run_with_devices("benchmarks.bench_comm_volume", 1, env), end="")
+        print(run_with_devices("benchmarks.bench_interconnect_model", 1, env),
+              end="")
 
         # multi-device sections (subprocess with forced device count)
         print(run_with_devices("benchmarks.bench_scenarios", 4, env), end="")
@@ -149,11 +145,6 @@ def main() -> None:
     # "_"-prefixed keys are metadata, not timing rows: compare.py ignores
     # them for gating and surfaces provenance next to failures
     blob["_provenance"] = provenance()
-    from repro.obs.metrics import get_registry
-
-    snap = get_registry().snapshot()
-    if snap:
-        blob["_metrics"] = snap
     with open(out, "w") as f:
         json.dump(blob, f, indent=1, sort_keys=True)
     sys.stderr.write(f"[bench] wrote {out}\n")

@@ -10,7 +10,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_reduced
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_cache, init_params
@@ -27,7 +26,7 @@ def main() -> None:
 
     cfg = get_reduced(args.arch)
     mesh = make_host_mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         key = jax.random.PRNGKey(0)
         params = init_params(cfg, key)
         B, P, T = args.batch, args.prompt_len, args.new_tokens

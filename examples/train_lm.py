@@ -13,7 +13,6 @@ import dataclasses
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs import get_reduced
 from repro.data import SyntheticLM
 from repro.launch.mesh import make_host_mesh
@@ -40,7 +39,7 @@ def main() -> None:
 
     cfg = config_100m()
     mesh = make_host_mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(0))
         print(f"model: {param_count(params)/1e6:.1f}M params")
         opt = adamw_init(params)

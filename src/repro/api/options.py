@@ -20,7 +20,7 @@ import enum
 
 from repro.core.partition import STRATEGIES
 from repro.core.solver import COMM_MODES, SCHED_MODES, SolverConfig
-from repro.kernels.ops import BACKENDS
+from repro.kernels.ops import BACKENDS, default_block_size
 
 AUTO = "auto"
 
@@ -66,7 +66,8 @@ class PlanOptions:
     auto mode share one partition, so auto-tuning never re-analyses).
     """
 
-    block_size: int = 32
+    # B: 128 where Pallas compiles for the TPU, 32 elsewhere
+    block_size: int = dataclasses.field(default_factory=default_block_size)
     sched: Sched = Sched.LEVELSET
     comm: Comm = Comm.ZEROCOPY
     partition: PartitionStrategy = PartitionStrategy.TASKPOOL

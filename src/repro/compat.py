@@ -1,70 +1,30 @@
-"""Version-compatibility shims for the jax API surface.
+"""The repo's one home for mesh construction and ``shard_map``.
 
-The repo targets the modern API (``jax.shard_map``, ``jax.make_mesh`` with
-``axis_types=jax.sharding.AxisType.Auto``); pinned containers ship jax 0.4.x
-where ``shard_map`` still lives in ``jax.experimental`` and ``AxisType`` does
-not exist. Every mesh construction and every ``shard_map`` in the repo routes
-through this module so version skew is handled in exactly one place.
+Every mesh and every ``shard_map`` in the repo routes through this module, so
+the axis types and the replication-check setting are chosen in one place.
+Targets jax 0.9.
 """
 from __future__ import annotations
-
-import numpy as np
 
 import jax
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types where supported, plain otherwise."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names), devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
-        )
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), devices=devices)
-    # pre-0.4.35: construct the Mesh directly
-    shape = tuple(axis_shapes)
-    n = int(np.prod(shape))
-    devs = np.asarray(devices if devices is not None else jax.devices()[:n])
-    return jax.sharding.Mesh(devs.reshape(shape), tuple(axis_names))
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized: newer jax returns a dict,
-    0.4.x returns a one-element list of dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
-def set_mesh(mesh):
-    """Ambient-mesh context manager: ``jax.set_mesh`` on new jax; on 0.4.x the
-    Mesh object is itself the context manager that sets the physical mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names), devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+    )
 
 
 def get_mesh():
-    """The ambient mesh set by :func:`set_mesh`, or None when unset/empty."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        return None if m is None or m.empty else m
-    from jax._src import mesh as mesh_lib
-
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The ambient mesh set by ``jax.set_mesh``, or None when unset/empty."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (replication checks off) across jax versions."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with replication checks off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

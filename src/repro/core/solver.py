@@ -61,7 +61,9 @@ from repro.core.partition import (
 from repro.kernels import ops
 from repro.obs.trace import get_tracer
 from repro.sparse.matrix import CSR, reverse_transpose
-from repro.kernels.superstep import superstep_call
+from repro.kernels.superstep import (
+    from_rows, row_scratch_bytes, superstep_call, to_rows,
+)
 
 AXIS = "x"  # device axis name used by the solver
 
@@ -83,7 +85,9 @@ def _check_choice(name: str, value, valid: tuple) -> None:
 
 @dataclasses.dataclass(frozen=True)
 class SolverConfig:
-    block_size: int = 32
+    # B: 128 where Pallas compiles for the TPU, 32 elsewhere
+    # (ops.default_block_size says why)
+    block_size: int = dataclasses.field(default_factory=ops.default_block_size)
     comm: str = "zerocopy"  # "zerocopy" | "unified"
     sched: str = "levelset"  # "levelset" | "dagpart" | "syncfree"
     partition: str = "taskpool"  # "taskpool" | "contiguous" | "malleable"
@@ -256,7 +260,7 @@ def _tiles_by_device(bs: BlockStructure, part: Partition, D: int) -> list:
 
 
 def build_plan(
-    a: CSR, n_devices: int, config: SolverConfig = SolverConfig(),
+    a: CSR, n_devices: int, config: SolverConfig | None = None,
     *, transpose: bool = False, part: Partition | None = None,
     verify: str | None = None,
 ) -> Plan:
@@ -271,6 +275,7 @@ def build_plan(
     error grade (or any finding at all for ``"strict"``). ``None`` defers to
     the ``REPRO_VERIFY`` environment variable (``1`` = strict, unset = off).
     """
+    config = config if config is not None else SolverConfig()
     with get_tracer().span("sptrsv.schedule", n_devices=n_devices,
                            sched=config.sched, comm=config.comm,
                            transpose=transpose) as span:
@@ -287,7 +292,7 @@ def build_plan(
 
 
 def _build_plan(
-    a: CSR, n_devices: int, config: SolverConfig = SolverConfig(),
+    a: CSR, n_devices: int, config: SolverConfig,
     *, transpose: bool = False, part: Partition | None = None,
 ) -> Plan:
     if transpose:
@@ -581,7 +586,10 @@ def fused_segments(plan: Plan) -> np.ndarray:
 # streaming HBM tile store (kernel_backend="fused_streamed", or auto-upgrade)
 # ---------------------------------------------------------------------------
 
-DEFAULT_STREAM_VMEM_LIMIT = 8 * 2**20  # bytes; ~half a TPU core's VMEM
+# bytes of resident store above which "fused" streams; the resident kernel
+# asks for this plus its row scratch and 16 MiB of headroom
+# (superstep_call's vmem_limit_bytes), far inside a v5e core's 128 MiB
+DEFAULT_STREAM_VMEM_LIMIT = 8 * 2**20
 
 
 def stream_vmem_limit() -> int:
@@ -639,20 +647,19 @@ def streamed_stores(plan: Plan) -> tuple[np.ndarray, np.ndarray]:
 
 
 def fused_vmem_bytes(plan: Plan, R: int = 1, *, streamed: bool = False) -> int:
-    """Estimated peak VMEM footprint (bytes) of one fused superstep launch.
+    """VMEM bytes one fused superstep launch allocates (what
+    :func:`repro.kernels.superstep.superstep_call` reserves).
 
-    Resident: the whole ``diag`` + per-device ``tiles`` stores ride in VMEM,
-    so the footprint grows with the total tile count. Streamed: the stores
-    stay in HBM and only two double-buffers sized by the *widest superstep
-    slice* are resident (per level when unmerged, per merge group for
-    dagpart). Carries (in + out windows) and the rhs are counted in both.
+    Resident: the whole ``diag`` + per-device ``tiles`` stores sit in VMEM
+    (one copy), so the footprint grows with the total tile count. Streamed:
+    the stores stay in HBM and only two double-buffers sized by the *widest
+    superstep slice* are resident (per level when unmerged, per merge group
+    for dagpart). The rhs and the carries stay in HBM in both; only the
+    row scratch they pass through is counted.
     """
     B = plan.bs.B
     itemsize = 4
-    vec = (plan.bs.nb + 1) * B * max(1, R) * itemsize
-    n_carry = 3 if (plan.config.comm == "unified" and plan.n_devices > 1
-                    and plan.n_boundary_rows > 0) else 2
-    vecs = (2 * n_carry + 1) * vec  # carry in + carry out windows + b_pad
+    vecs = row_scratch_bytes(B, R, itemsize)
     if streamed:
         if plan.n_levels:
             wid = step_widths(plan)
@@ -812,6 +819,8 @@ def _fused_levelset_device_fn(plan: Plan):
         wid_a = jnp.asarray(wid)
         seg_a = jnp.asarray(seg_tab)
         stp_a = jnp.asarray(so.astype(np.int32))
+        multi = b_pad.ndim == 3
+        b_pad = to_rows(b_pad)  # the kernel's (nb+1, R, B) row panels
         z = jnp.zeros_like(b_pad)
 
         if has_ex:
@@ -858,7 +867,7 @@ def _fused_levelset_device_fn(plan: Plan):
 
         init = (z, z, z) if unified else (z, z)
         carry = jax.lax.fori_loop(0, n_seg, body, init)
-        x = carry[-1]
+        x = from_rows(carry[-1], multi)
         with jax.named_scope("sptrsv.gather"):
             xg = x * ops.bcast_trailing(owner_mask, x)
             if D > 1:
@@ -888,16 +897,17 @@ def solve_local(plan: Plan, b_blocks: jax.Array) -> jax.Array:
             diag_s, tiles_s = streamed_stores(plan)
             diag, tiles = jnp.asarray(diag_s[0]), jnp.asarray(tiles_s[0])
             sw, uw = stream_widths(plan)
-        acc0 = jnp.zeros_like(b_pad)
+        b_rows = to_rows(b_pad)
+        acc0 = jnp.zeros_like(b_rows)
         seg = jnp.array([0, plan.n_supersteps], jnp.int32)
         stp = jnp.asarray(step_offsets(plan))
         _, x = superstep_call(
-            seg, off, wid, sr, ut, trow, tcol, diag, tiles, b_pad, acc0, acc0,
+            seg, off, wid, sr, ut, trow, tcol, diag, tiles, b_rows, acc0, acc0,
             stp=stp, grid=max(1, plan.n_supersteps),
             interpret=ops.interpret_mode(),
             stream=streamed, solve_widths=sw, upd_widths=uw,
         )
-        return x[:nb]
+        return from_rows(x, b_pad.ndim == 3)[:nb]
     diag = jnp.asarray(plan.diag)
     sr = jnp.asarray(plan.solve_rows[0])
     ut = jnp.asarray(plan.upd_tiles[0])
@@ -1267,6 +1277,14 @@ class DistributedSolver:
         self.plan = plan
         self._args = self._plan_args(plan)
 
+    def lower(self, R: int = 1) -> "jax.stages.Lowered":
+        """The executor lowered for an R-column right-hand side (``R = 1``:
+        a single vector) — ``.compile().as_text()`` shows what runs."""
+        nb, B = self.plan.bs.nb, self.plan.bs.B
+        shape = (nb + 1, B) if R == 1 else (nb + 1, B, R)
+        return self._jitted.lower(
+            *self._args, jax.ShapeDtypeStruct(shape, jnp.float32))
+
     def solve_blocks(self, b_blocks: jax.Array) -> jax.Array:
         """b_blocks: (nb, B) or a multi-RHS panel (nb, B, R) -> same shape."""
         self.n_solves += 1
@@ -1290,7 +1308,7 @@ class DistributedSolver:
 
 def sptrsv(
     a: CSR, b: np.ndarray, *, mesh: jax.sharding.Mesh | None = None,
-    config: SolverConfig = SolverConfig(), transpose: bool = False,
+    config: SolverConfig | None = None, transpose: bool = False,
 ) -> np.ndarray:
     """Deprecated one-shot API: analyse, plan, solve Lx=b (or L^T x=b).
 

@@ -10,6 +10,9 @@ jnp segment reduction, mirroring the paper's device-side atomics).
 ``block_gemv_grouped`` processes G tiles per grid program so each MXU call is
 a (G*B, B) × (B,) batched matvec — the grouped layout raises MXU utilization
 (§Perf hillclimb knob).
+
+Every dot sets ``precision=HIGHEST``: the TPU's default f32 dot is a single
+bf16 pass, far outside the solver's tolerance.
 """
 from __future__ import annotations
 
@@ -19,18 +22,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _gemv_kernel(t_ref, x_ref, o_ref):
-    # t_ref: (1,B,B), x_ref: (1,B), o_ref: (1,B)
-    o_ref[0, :] = jnp.dot(
-        t_ref[0], x_ref[0, :], preferred_element_type=t_ref.dtype
-    )
+    # t_ref: (1,B,B), x_ref/o_ref: (1,1,B) — the product as a row: x @ T^T
+    o_ref[0] = jax.lax.dot_general(
+        x_ref[0], t_ref[0], (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).astype(o_ref.dtype)
 
 
 def _gemv_grouped_kernel(t_ref, x_ref, o_ref):
     # t_ref: (G,B,B), x_ref: (G,B), o_ref: (G,B) — one fused batched matvec
     o_ref[...] = jnp.einsum(
-        "gij,gj->gi", t_ref[...], x_ref[...], preferred_element_type=t_ref.dtype
+        "gij,gj->gi", t_ref[...], x_ref[...], precision=HIGHEST,
+        preferred_element_type=t_ref.dtype,
     )
 
 
@@ -38,22 +45,24 @@ def _gemv_grouped_kernel(t_ref, x_ref, o_ref):
 def block_gemv(tiles: jax.Array, xs: jax.Array, *, interpret: bool = False) -> jax.Array:
     """Per-tile products: tiles (m,B,B) @ xs (m,B) -> (m,B)."""
     m, B, _ = tiles.shape
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gemv_kernel,
         grid=(m,),
         in_specs=[
             pl.BlockSpec((1, B, B), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, B), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, B), tiles.dtype),
+        out_specs=pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, 1, B), tiles.dtype),
         interpret=interpret,
-    )(tiles, xs)
+    )(tiles, xs.reshape(m, 1, B))
+    return out.reshape(m, B)
 
 
 def _gemm_kernel(t_ref, x_ref, o_ref):
     # t_ref: (1,B,B), x_ref: (1,B,R), o_ref: (1,B,R) — per-tile (B,B)@(B,R)
-    o_ref[0] = jnp.dot(t_ref[0], x_ref[0], preferred_element_type=t_ref.dtype)
+    o_ref[0] = jnp.dot(t_ref[0], x_ref[0], precision=HIGHEST,
+                       preferred_element_type=t_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

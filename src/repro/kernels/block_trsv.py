@@ -9,6 +9,12 @@ Two in-kernel algorithms:
 * ``panel``      — processes P=8 rows per step: a tiny unrolled P×P triangle
   followed by a rank-P MXU update of the remaining rhs. ~P× fewer sequential
   steps; this is the §Perf variant (hillclimbed in EXPERIMENTS.md).
+
+Layout: every block's last two dims equal the array's (``(1, B, B)`` tiles,
+``(1, 1, B)`` rhs rows, ``(1, B, R)`` panels), which is what the TPU compiler
+accepts for any B. Rows of a tile are read from the ref at a dynamic sublane
+offset (``l_ref[0, pl.ds(i, 1), :]``); Mosaic has no ``dynamic_slice`` on
+values.
 """
 from __future__ import annotations
 
@@ -18,32 +24,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _trsv_rowsweep_kernel(l_ref, r_ref, x_ref):
-    # l_ref: (1,B,B)  r_ref/x_ref: (1,B)
+    # l_ref: (1,B,B)  r_ref/x_ref: (1,1,B)
     B = l_ref.shape[-1]
-    L = l_ref[0]  # (B,B) loaded to VMEM/registers
-    r = r_ref[...]  # (1,B)
+    r = r_ref[0]  # (1,B)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
 
     def body(i, x):
         # partial dot over solved prefix: sum_j<i L[i,j] * x[j]
-        li = jax.lax.dynamic_slice(L, (i, 0), (1, B))  # (1,B) row i
-        s = jnp.sum(jnp.where(col < i, li * x, 0.0))
-        lii = jnp.sum(jnp.where(col == i, li, 0.0))
-        ri = jnp.sum(jnp.where(col == i, r, 0.0))
-        xi = (ri - s) / lii
-        return jnp.where(col == i, xi, x)
+        li = l_ref[0, pl.ds(i, 1), :]  # (1,B) row i
+        s = jnp.sum(jnp.where(col < i, li * x, 0.0), axis=1, keepdims=True)
+        lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
+        ri = jnp.sum(jnp.where(col == i, r, 0.0), axis=1, keepdims=True)
+        return jnp.where(col == i, (ri - s) / lii, x)
 
-    x_ref[...] = jax.lax.fori_loop(0, B, body, jnp.zeros((1, B), l_ref.dtype))
+    x_ref[0] = jax.lax.fori_loop(0, B, body, jnp.zeros((1, B), l_ref.dtype))
 
 
 def _trsv_panel_kernel(l_ref, r_ref, x_ref, *, panel: int):
     # Panel forward substitution: solve P rows with the row sweep, then one
-    # (B,P)@(P,) MXU-shaped rank-P update of the remaining rhs.
+    # MXU-shaped rank-P update of the remaining rhs.
     B = l_ref.shape[-1]
     P = panel
-    L = l_ref[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
 
     def outer(p, carry):
@@ -52,26 +57,28 @@ def _trsv_panel_kernel(l_ref, r_ref, x_ref, *, panel: int):
 
         def inner(q, x):
             i = base + q
-            li = jax.lax.dynamic_slice(L, (i, 0), (1, B))
+            li = l_ref[0, pl.ds(i, 1), :]
             in_panel_prefix = jnp.logical_and(col >= base, col < i)
-            s = jnp.sum(jnp.where(in_panel_prefix, li * x, 0.0))
-            lii = jnp.sum(jnp.where(col == i, li, 0.0))
-            ri = jnp.sum(jnp.where(col == i, r, 0.0))
-            xi = (ri - s) / lii
-            return jnp.where(col == i, xi, x)
+            s = jnp.sum(jnp.where(in_panel_prefix, li * x, 0.0), axis=1,
+                        keepdims=True)
+            lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
+            ri = jnp.sum(jnp.where(col == i, r, 0.0), axis=1, keepdims=True)
+            return jnp.where(col == i, (ri - s) / lii, x)
 
         x = jax.lax.fori_loop(0, P, inner, x)
-        # rank-P update of the trailing rhs: r -= L[:, base:base+P] @ x[base:base+P]
-        Lp = jax.lax.dynamic_slice(L, (0, base), (B, P))  # (B,P)
-        xp = jax.lax.dynamic_slice(x, (0, base), (1, P))  # (1,P)
-        upd = jnp.dot(Lp, xp[0], preferred_element_type=jnp.float32)  # (B,)
-        r = jnp.where(col >= base + P, r - upd[None, :], r)
+        # rank-P update of the trailing rhs: r -= L[:, base:base+P] @ x[base:base+P],
+        # i.e. (x masked to the panel) @ L^T in row layout
+        in_panel = jnp.logical_and(col >= base, col < base + P)
+        upd = jax.lax.dot_general(
+            jnp.where(in_panel, x, 0.0), l_ref[0], (((1,), (1,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32)  # (1,B)
+        r = jnp.where(col >= base + P, r - upd, r)
         return r, x
 
     _, x = jax.lax.fori_loop(
-        0, B // P, outer, (r_ref[...], jnp.zeros((1, B), l_ref.dtype))
+        0, B // P, outer, (r_ref[0], jnp.zeros((1, B), l_ref.dtype))
     )
-    x_ref[...] = x
+    x_ref[0] = x
 
 
 def _trsm_rowsweep_kernel(l_ref, r_ref, x_ref):
@@ -80,20 +87,18 @@ def _trsm_rowsweep_kernel(l_ref, r_ref, x_ref):
     # masked (1,B)@(B,R) matmul — one MXU call amortized over all R systems.
     B = l_ref.shape[-1]
     R = r_ref.shape[-1]
-    L = l_ref[0]  # (B,B)
-    r = r_ref[0]  # (B,R)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
 
     def body(i, x):
-        li = jax.lax.dynamic_slice(L, (i, 0), (1, B))  # (1,B) row i
+        li = l_ref[0, pl.ds(i, 1), :]  # (1,B) row i
         s = jnp.dot(
-            jnp.where(col < i, li, 0.0), x, preferred_element_type=jnp.float32
+            jnp.where(col < i, li, 0.0), x, precision=HIGHEST,
+            preferred_element_type=jnp.float32,
         )  # (1,R) partial dots over the solved prefix, all RHS at once
-        lii = jnp.sum(jnp.where(col == i, li, 0.0))
-        ri = jax.lax.dynamic_slice(r, (i, 0), (1, R))  # (1,R)
-        xi = (ri - s) / lii
-        return jnp.where(row == i, xi, x)
+        lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
+        ri = r_ref[0, pl.ds(i, 1), :]  # (1,R)
+        return jnp.where(row == i, (ri - s) / lii, x)
 
     x_ref[0] = jax.lax.fori_loop(0, B, body, jnp.zeros((B, R), l_ref.dtype))
 
@@ -134,14 +139,15 @@ def block_trsv(
         kernel = functools.partial(_trsv_panel_kernel, panel=panel)
     else:
         raise ValueError(algorithm)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(k,),
         in_specs=[
             pl.BlockSpec((1, B, B), lambda i: (i, 0, 0)),  # one tile in VMEM per program
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, B), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, B), diag.dtype),
+        out_specs=pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, 1, B), diag.dtype),
         interpret=interpret,
-    )(diag, rhs)
+    )(diag, rhs.reshape(k, 1, B))
+    return out.reshape(k, B)

@@ -74,6 +74,17 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def default_block_size() -> int:
+    """Tile size B when the caller sets none: 128 where Pallas compiles for
+    the TPU, 32 elsewhere. Mosaic lays f32 arrays out in 128-lane tiles and
+    slices HBM and VMEM refs only at multiples of them, so the superstep
+    kernel's per-step DMAs and row reads need ``B % 128 == 0`` (at B=32 it
+    does not compile, and at 2^20 rows its schedule would also outgrow the
+    1 MiB of SMEM). Interpreted kernels have no such limit, and the smaller
+    tile stores fewer dense zeros on scattered patterns."""
+    return 32 if _interpret() else 128
+
+
 def interpret_mode() -> bool:
     """Whether Pallas kernels (incl. the superstep megakernel) run interpreted."""
     return _interpret()

@@ -8,6 +8,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# f32 dots on the TPU default to one bf16 pass; the solver needs full f32.
+# (``triangular_solve`` already expands with HIGHEST-precision dots.)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def block_trsv_ref(diag: jax.Array, rhs: jax.Array) -> jax.Array:
     """Batched dense lower-triangular solve.
@@ -26,8 +30,8 @@ def block_trsv_ref(diag: jax.Array, rhs: jax.Array) -> jax.Array:
 def block_gemv_ref(tiles: jax.Array, xs: jax.Array) -> jax.Array:
     """Batched tile*vector: tiles (m,B,B), xs (m,B) or (m,B,R) panels."""
     if xs.ndim == 3:
-        return jnp.einsum("mij,mjr->mir", tiles, xs)
-    return jnp.einsum("mij,mj->mi", tiles, xs)
+        return jnp.einsum("mij,mjr->mir", tiles, xs, precision=HIGHEST)
+    return jnp.einsum("mij,mj->mi", tiles, xs, precision=HIGHEST)
 
 
 def fused_level_ref(

@@ -12,11 +12,12 @@ Scalar-prefetch layout
 ----------------------
 The ragged compacted schedule rides in as scalar-prefetch operands (SMEM on
 TPU, available before the kernel body runs, so schedule reads never touch
-HBM):
+HBM). The two level tables are flattened to 1-D so SMEM holds them unpadded:
 
 * ``seg``  ``(2,)``   — ``[first_step, n_active_steps]`` of this launch.
-* ``off``  ``(T, 3)`` — per-level start offsets into the three flats.
-* ``wid``  ``(T, 3)`` — per-level bucket widths ``(w_solve, w_upd, w_ex)``.
+* ``off``  ``(3T,)``  — per-level start offsets into the three flats
+  (level ``t``'s entries at ``3t .. 3t+2``).
+* ``wid``  ``(3T,)``  — per-level bucket widths ``(w_solve, w_upd, w_ex)``.
 * ``stp``  ``(n_steps+1,)`` — level offsets of the supersteps: step ``s``
   covers levels ``[stp[s], stp[s+1])``. Identity (``arange``) for levelset;
   the DAG-partition merge pass's coarsening for ``sched="dagpart"``.
@@ -28,22 +29,28 @@ Grid = one program per *superstep*; program ``p`` executes the levels of step
 ``seg[0] + p`` in order (programs beyond ``seg[1]`` are inert padding, which
 lets a ``fori_loop`` over variable-length segments reuse one traced launch).
 A merged step's levels run back-to-back inside one program — the sequential
-rowsweep is exactly what makes intra-step dependencies legal. TPU grid
-programs run sequentially on a core, so the carry buffers (``acc``, ``x``,
-and ``delta`` for the unified split) persist in the output windows across
-steps — step ``s+1`` reads the partial sums step ``s`` wrote without any HBM
-round-trip. Program 0 copies the incoming carries into the output windows
-(one copy per launch; see the aliasing note in :func:`superstep_call`).
+rowsweep is exactly what makes intra-step dependencies legal.
 
-Each program walks its levels' slices of the schedule with in-kernel loops
-bounded by the *bucket width* (dynamic trip counts, so a 3-row level costs a
-width-4 loop, not the global max): per row a dense forward substitution of the
-diagonal tile, then per tile a ``(B,B)@(B[,R])`` MXU product accumulated into
-the destination row of ``acc`` (or ``delta``). The in-kernel arithmetic
-mirrors ``block_trsv``/``block_trsm``/``block_gemv``/``block_gemm``
-expression-for-expression, so the fused kernel is bit-exact with the
-``lax.switch`` executor running the per-op Pallas backend — the property
-``tests/test_superstep.py`` pins down in interpret mode.
+Carries in HBM
+--------------
+The right-hand side and the carries (``acc``, ``x``, and ``delta`` for the
+unified split) are *row panels* of shape ``(nb+1, R, B)`` — one ``(R, B)``
+row per block row, ``R = 1`` for a single right-hand side — and stay in HBM
+(``ANY``). Each solve and each tile update moves the rows it touches through
+a small VMEM scratch with synchronous DMAs, so VMEM use does not grow with
+``n`` (at 2^20 rows a carry is 4 MiB for one RHS and 32 MiB for eight, more
+than VMEM could hold alongside the stores). The ``(R, B)`` orientation keeps
+B on the lanes, which is also what the DMA engine requires of a slice. TPU
+grid programs run sequentially on a core, so program 0 copies the incoming
+carries into the output buffers and every later program reads the partial
+sums earlier ones wrote.
+
+Per row a dense forward substitution of the diagonal tile (B masked lane
+reductions), then per tile a ``(R,B)@(B,B)^T`` MXU product accumulated into
+the destination row of ``acc`` (or ``delta``). Every product is exact on
+dyadic values, so the fused kernel agrees bit-for-bit with the
+``lax.switch`` executor there — the property ``tests/test_superstep.py`` pins
+down in interpret mode — and to float tolerance elsewhere.
 
 Collectives cannot live inside a Pallas kernel, so the boundary exchange
 splits the level range into *segments* (``core.solver.fused_segments``): one
@@ -52,9 +59,9 @@ cuts fuse the entire solve into exactly one launch.
 
 Resident vs streamed stores
 ---------------------------
-In the **resident** variant all operands ride in whole (full-array block
-specs): fine while ``diag``/``tiles`` fit VMEM, but the footprint grows with
-the *total* tile count, which caps the matrix sizes the fused hot path can
+In the **resident** variant ``diag``/``tiles`` sit whole in VMEM (one copy,
+no pipelining): fine while they fit, but the footprint grows with the
+*total* tile count, which caps the matrix sizes the fused hot path can
 serve. The **streamed** variant (``stream=True``) is the production-scale
 path: ``diag``/``tiles`` arrive *schedule-ordered* (level ``t``'s slice is
 contiguous at ``off[t]`` — exactly the compacted flat layout, so a merged
@@ -64,11 +71,8 @@ DMA, prefetching step ``s+1`` while step ``s`` computes. VMEM residency then
 scales with the *widest superstep slice* (max summed widths over the step
 table), not the total tile store, and the DMA engine sees exactly one
 contiguous burst per step per store. The DMA sizes branch over the distinct
-step widths (a
-static ladder of ≤ ``MAX_BUCKETS`` sizes), so the bytes moved per solve equal
-the compacted schedule footprint — no pad-to-max traffic. The in-kernel
-arithmetic is shared with the resident variant op-for-op, so streamed,
-resident, and ``lax.switch`` execution are mutually bit-identical.
+step widths (a static ladder of ≤ ``MAX_BUCKETS`` sizes), so the bytes moved
+per solve equal the compacted schedule footprint — no pad-to-max traffic.
 """
 from __future__ import annotations
 
@@ -80,6 +84,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 N_PREFETCH = 8  # seg, off, wid, stp, sr, ut, trow, tcol
+N_ROW_BUFS = 3  # VMEM row scratch: two operand rows + one result row
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 # Trace-time record of the most recent streamed launch's VMEM scratch shapes
 # (diag_buf/tile_buf) — lets tests assert the streaming contract (buffers
@@ -102,50 +109,43 @@ def stream_scratch_shapes(solve_widths: tuple, upd_widths: tuple, B: int
     return (2, WS, B, B), (2, WU, B, B)
 
 
-def _solve_tile(L, rhs):
-    """(B,B) lower-triangular solve of one rhs vector (B,).
+def row_scratch_bytes(B: int, R: int = 1, itemsize: int = 4) -> int:
+    """VMEM bytes of the kernel's row scratch (``N_ROW_BUFS`` rows of
+    ``(R, B)``) — the only VMEM the HBM carries cost."""
+    return N_ROW_BUFS * max(1, R) * B * itemsize
 
-    Mirrors ``block_trsv._trsv_rowsweep_kernel`` op-for-op (masked full-row
-    dots over a (1,B) working vector) so results are bit-identical.
-    """
-    B = L.shape[-1]
+
+def to_rows(v: jax.Array) -> jax.Array:
+    """``(N, B)`` vectors or ``(N, B, R)`` panels -> the kernel's ``(N, R, B)``
+    row-panel layout (B on the lanes)."""
+    return v[:, None, :] if v.ndim == 2 else jnp.swapaxes(v, 1, 2)
+
+
+def from_rows(v: jax.Array, multi: bool) -> jax.Array:
+    """Inverse of :func:`to_rows`."""
+    return jnp.swapaxes(v, 1, 2) if multi else v[:, 0, :]
+
+
+def _solve_rows(l_ref, rhs):
+    """Solve ``L X = RHS`` for one ``(B,B)`` lower-triangular tile, with the
+    right-hand sides as the rows of ``rhs`` ``(R,B)``: B steps of masked lane
+    reductions, row ``i`` of L read from the ref at a dynamic sublane."""
+    B = l_ref.shape[-1]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-    r = rhs.reshape(1, B)
 
     def body(i, x):
-        li = jax.lax.dynamic_slice(L, (i, 0), (1, B))
-        s = jnp.sum(jnp.where(col < i, li * x, 0.0))
-        lii = jnp.sum(jnp.where(col == i, li, 0.0))
-        ri = jnp.sum(jnp.where(col == i, r, 0.0))
-        xi = (ri - s) / lii
-        return jnp.where(col == i, xi, x)
+        li = l_ref[pl.ds(i, 1), :]  # (1,B) row i
+        s = jnp.sum(jnp.where(col < i, li * x, 0.0), axis=1, keepdims=True)
+        lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
+        ri = jnp.sum(jnp.where(col == i, rhs, 0.0), axis=1, keepdims=True)
+        return jnp.where(col == i, (ri - s) / lii, x)
 
-    return jax.lax.fori_loop(0, B, body, jnp.zeros((1, B), L.dtype))[0]
-
-
-def _solve_tile_panel(L, rhs):
-    """(B,B) solve of a (B,R) panel; mirrors ``_trsm_rowsweep_kernel``."""
-    B = L.shape[-1]
-    R = rhs.shape[-1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
-
-    def body(i, x):
-        li = jax.lax.dynamic_slice(L, (i, 0), (1, B))
-        s = jnp.dot(
-            jnp.where(col < i, li, 0.0), x, preferred_element_type=jnp.float32
-        )
-        lii = jnp.sum(jnp.where(col == i, li, 0.0))
-        ri = jax.lax.dynamic_slice(rhs, (i, 0), (1, R))
-        xi = (ri - s) / lii
-        return jnp.where(row == i, xi, x)
-
-    return jax.lax.fori_loop(0, B, body, jnp.zeros((B, R), L.dtype))
+    return jax.lax.fori_loop(0, B, body, jnp.zeros_like(rhs))
 
 
 def _superstep_kernel(
     seg_ref, off_ref, wid_ref, stp_ref, sr_ref, ut_ref, trow_ref, tcol_ref,
-    diag_ref, tiles_ref, b_ref, *io_refs, multi: bool, split_delta: bool,
+    diag_ref, tiles_ref, b_ref, *refs, split_delta: bool,
     stream: bool = False, solve_widths: tuple = (), upd_widths: tuple = (),
 ):
     """Shared kernel body for the resident and streamed variants.
@@ -158,21 +158,28 @@ def _superstep_kernel(
     branch per distinct width in the static ladder, so start/wait always
     agree on size).
     """
+    n_carry = 3 if split_delta else 2
+    carry_in, carry_out = refs[:n_carry], refs[n_carry:2 * n_carry]
     if stream:
-        *io_refs, dbuf, tbuf, dsem, tsem = io_refs
-    if split_delta:
-        acc_in, delta_in, x_in, acc_ref, delta_ref, x_ref = io_refs
+        dbuf, tbuf, dsem, tsem, rows = refs[2 * n_carry:]
     else:
-        acc_in, x_in, acc_ref, x_ref = io_refs
+        (rows,) = refs[2 * n_carry:]
+    if split_delta:
+        acc_ref, delta_ref, x_ref = carry_out
+    else:
+        acc_ref, x_ref = carry_out
         delta_ref = acc_ref  # tile updates land in acc (the zerocopy/local carry)
     p = pl.program_id(0)
 
     @pl.when(p == 0)
-    def _():  # materialize the donated carries in the output windows
-        acc_ref[...] = acc_in[...]
-        x_ref[...] = x_in[...]
-        if split_delta:
-            delta_ref[...] = delta_in[...]
+    def _():  # HBM -> HBM: the incoming carries seed the output buffers
+        pltpu.sync_copy(carry_in, carry_out)
+
+    def row(k):
+        return rows.at[pl.ds(k, 1)]
+
+    def at_row(ref, r):
+        return ref.at[pl.ds(r, 1)]
 
     s = seg_ref[0] + p
     slot = jax.lax.rem(p, 2)
@@ -188,17 +195,17 @@ def _superstep_kernel(
             sq = seg_ref[0] + q
             t0 = stp_ref[sq]
             t1 = stp_ref[sq + 1] - 1  # last level of the step (steps non-empty)
-            wsq = off_ref[t1, 0] + wid_ref[t1, 0] - off_ref[t0, 0]
-            wuq = off_ref[t1, 1] + wid_ref[t1, 1] - off_ref[t0, 1]
+            wsq = off_ref[3 * t1] + wid_ref[3 * t1] - off_ref[3 * t0]
+            wuq = off_ref[3 * t1 + 1] + wid_ref[3 * t1 + 1] - off_ref[3 * t0 + 1]
             for w in solve_widths:
                 if w > 0:
                     yield wsq == w, pltpu.make_async_copy(
-                        diag_ref.at[pl.ds(off_ref[t0, 0], w)],
+                        diag_ref.at[pl.ds(off_ref[3 * t0], w)],
                         dbuf.at[sl, pl.ds(0, w)], dsem.at[sl])
             for w in upd_widths:
                 if w > 0:
                     yield wuq == w, pltpu.make_async_copy(
-                        tiles_ref.at[pl.ds(off_ref[t0, 1], w)],
+                        tiles_ref.at[pl.ds(off_ref[3 * t0 + 1], w)],
                         tbuf.at[sl, pl.ds(0, w)], tsem.at[sl])
 
         @pl.when(jnp.logical_and(p == 0, seg_ref[1] > 0))
@@ -220,55 +227,57 @@ def _superstep_kernel(
         t_lo = stp_ref[s]
         # streamed scratch holds the whole step slice; level t's entries sit
         # at (off[t] - base) within it
-        base_s = off_ref[t_lo, 0]
-        base_u = off_ref[t_lo, 1]
+        base_s = off_ref[3 * t_lo]
+        base_u = off_ref[3 * t_lo + 1]
 
         def micro(t, carry):
             # --- solve level t's owned rows (dynamic trip = bucket width) ---
-            o_s = off_ref[t, 0]
+            o_s = off_ref[3 * t]
 
             def solve_one(i, c):
                 r = sr_ref[o_s + i]
 
                 @pl.when(r >= 0)
                 def _():
-                    L = dbuf[slot, o_s - base_s + i] if stream else diag_ref[r]
-                    rhs = b_ref[r] - acc_ref[r]
+                    l_ref = (dbuf.at[slot, o_s - base_s + i] if stream
+                             else diag_ref.at[r])
+                    pltpu.sync_copy((at_row(b_ref, r), at_row(acc_ref, r)),
+                                    (row(0), row(1)))
+                    rhs = rows[0] - rows[1]
                     if split_delta:
                         # earlier levels of this merged step accumulated local
                         # contributions into delta (not yet psum-folded into
                         # acc) — intra-step dependencies read them here. For
                         # an unmerged step delta is exactly +0.0: bit-inert.
-                        rhs = rhs - delta_ref[r]
-                    x_ref[r] = (_solve_tile_panel(L, rhs) if multi
-                                else _solve_tile(L, rhs))
+                        pltpu.sync_copy(at_row(delta_ref, r), row(1))
+                        rhs = rhs - rows[1]
+                    rows[2] = _solve_rows(l_ref, rhs)
+                    pltpu.sync_copy(row(2), at_row(x_ref, r))
 
                 return c
 
-            jax.lax.fori_loop(0, wid_ref[t, 0], solve_one, 0)
+            jax.lax.fori_loop(0, wid_ref[3 * t], solve_one, 0)
 
             # --- owned-tile updates sourced at level t ---
-            o_u = off_ref[t, 1]
+            o_u = off_ref[3 * t + 1]
 
             def upd_one(j, c):
                 tid = ut_ref[o_u + j]
-                # keep the MXU product a standalone dot on materialized
-                # operands: letting XLA fuse the gathers or the accumulate
-                # into the dot changes its reduction codegen by 1 ulp vs the
-                # batched per-op kernels, breaking switch-executor
-                # bit-exactness
-                tile, xv = jax.lax.optimization_barrier(
-                    (tbuf[slot, o_u - base_u + j] if stream else tiles_ref[tid],
-                     x_ref[tcol_ref[tid]])
-                )
-                prod = jax.lax.optimization_barrier(
-                    jnp.dot(tile, xv, preferred_element_type=tile.dtype)
-                )
+                t_ref = (tbuf.at[slot, o_u - base_u + j] if stream
+                         else tiles_ref.at[tid])
                 rd = trow_ref[tid]
-                delta_ref[rd] = delta_ref[rd] + prod
+                pltpu.sync_copy(
+                    (at_row(x_ref, tcol_ref[tid]), at_row(delta_ref, rd)),
+                    (row(0), row(1)))
+                # the product as rows: X^T L^T, i.e. (R,B) @ (B,B)^T
+                prod = jax.lax.dot_general(
+                    rows[0], t_ref[...], (((1,), (1,)), ((), ())),
+                    precision=HIGHEST, preferred_element_type=jnp.float32)
+                rows[2] = rows[1] + prod
+                pltpu.sync_copy(row(2), at_row(delta_ref, rd))
                 return c
 
-            jax.lax.fori_loop(0, wid_ref[t, 1], upd_one, 0)
+            jax.lax.fori_loop(0, wid_ref[3 * t + 1], upd_one, 0)
             return carry
 
         # run the step's levels in order inside this one grid program — the
@@ -291,7 +300,7 @@ def superstep_call(
     tcol: jax.Array,  # (ML+1,) int32
     diag: jax.Array,  # (nb+1, B, B) resident; (S, B, B) schedule-ordered streamed
     tiles: jax.Array,  # (ML+1, B, B) resident; (U, B, B) schedule-ordered streamed
-    b_pad: jax.Array,  # (nb+1, B) or (nb+1, B, R)
+    b_pad: jax.Array,  # (nb+1, R, B) row panel (see to_rows)
     acc: jax.Array,
     x: jax.Array,
     delta: jax.Array | None = None,
@@ -306,12 +315,14 @@ def superstep_call(
 ):
     """One fused launch executing ``grid`` supersteps starting at ``seg[0]``.
 
-    ``stp`` is the superstep→level offset table; ``None`` means the identity
-    (one level per superstep — the plain levelset schedule). Returns the
-    updated ``(acc, x)`` carry, or ``(acc, delta, x)`` when ``split_delta``
-    (the unified executor's not-yet-exchanged contributions accumulate in
-    ``delta``; solves read ``acc + delta`` so later levels of a merged
-    superstep see the earlier levels' local contributions).
+    ``b_pad`` and the carries are ``(nb+1, R, B)`` row panels
+    (:func:`to_rows`). ``stp`` is the superstep→level offset table; ``None``
+    means the identity (one level per superstep — the plain levelset
+    schedule). Returns the updated ``(acc, x)`` carry, or ``(acc, delta, x)``
+    when ``split_delta`` (the unified executor's not-yet-exchanged
+    contributions accumulate in ``delta``; solves read ``acc + delta`` so
+    later levels of a merged superstep see the earlier levels' local
+    contributions).
 
     With ``stream=True`` the ``diag``/``tiles`` operands are the
     *schedule-ordered* stores (``core.solver.streamed_stores``): they stay in
@@ -319,7 +330,6 @@ def superstep_call(
     VMEM scratch sized by the max width in ``solve_widths`` / ``upd_widths``
     (the static ladder of distinct per-step summed widths).
     """
-    multi = b_pad.ndim == 3
     assert (delta is not None) == split_delta
     if off.shape[0] == 0:
         # empty schedule (0-level plan): every program is inert, but the
@@ -332,15 +342,15 @@ def superstep_call(
     if stp is None:
         stp = jnp.arange(off.shape[0] + 1, dtype=jnp.int32)
     carry_in = (acc, delta, x) if split_delta else (acc, x)
-    n_carry = len(carry_in)
+    R, B = b_pad.shape[1], b_pad.shape[2]
+    if not interpret and B % 128:
+        raise ValueError(
+            f"the superstep kernel needs B % 128 == 0 on the TPU (Mosaic "
+            f"slices refs in 128-lane tiles), got B={B}")
 
-    def vec_spec(a):
-        zeros = (0,) * a.ndim
-        return pl.BlockSpec(a.shape, lambda p, *refs: zeros)
-
-    scratch_shapes = []
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    vmem_bytes = row_scratch_bytes(B, R, b_pad.dtype.itemsize)
     if stream:
-        B = diag.shape[-1]
         # the streaming contract: VMEM scratch scales with the widest level
         # slice (double-buffered), never with the total store size
         dshape, tshape = stream_scratch_shapes(solve_widths, upd_widths, B)
@@ -350,20 +360,23 @@ def superstep_call(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ]
+        vmem_bytes += 2 * (dshape[1] + tshape[1]) * B * B * diag.dtype.itemsize
         LAST_STREAM_ALLOC.update(
             diag_buf=dshape, tile_buf=tshape,
             diag_store=tuple(diag.shape), tile_store=tuple(tiles.shape),
         )
-        store_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-        in_specs = [store_spec, store_spec] + [vec_spec(a) for a in (b_pad, *carry_in)]
+        store_spec = hbm
     else:
-        in_specs = [vec_spec(a) for a in (diag, tiles, b_pad, *carry_in)]
+        scratch_shapes = []
+        vmem_bytes += (diag.size + tiles.size) * diag.dtype.itemsize
+        store_spec = pl.BlockSpec(memory_space=pltpu.VMEM)  # whole, one copy
+    scratch_shapes.append(pltpu.VMEM((N_ROW_BUFS, R, B), b_pad.dtype))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=N_PREFETCH,
         grid=(grid,),
-        in_specs=in_specs,
-        out_specs=[vec_spec(a) for a in carry_in],
+        in_specs=[store_spec, store_spec] + [hbm] * (1 + len(carry_in)),
+        out_specs=[hbm] * len(carry_in),
         scratch_shapes=scratch_shapes,
     )
     # The carries are deliberately NOT donated via input_output_aliases:
@@ -372,18 +385,21 @@ def superstep_call(
     # x_ref writes clobber acc_ref on hardware. Program 0's explicit copy-in
     # already pays the one copy per launch that donation would have saved.
     kernel = functools.partial(
-        _superstep_kernel, multi=multi, split_delta=split_delta,
+        _superstep_kernel, split_delta=split_delta,
         stream=stream, solve_widths=solve_widths, upd_widths=upd_widths,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in carry_in),
+        # room for the stores/scratch above plus Mosaic's own working set
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(vmem_bytes + (16 << 20))),
         interpret=interpret,
     )(
-        seg.astype(jnp.int32), off.astype(jnp.int32), wid.astype(jnp.int32),
-        stp.astype(jnp.int32), sr.astype(jnp.int32), ut.astype(jnp.int32),
+        seg.astype(jnp.int32), off.astype(jnp.int32).reshape(-1),
+        wid.astype(jnp.int32).reshape(-1), stp.astype(jnp.int32),
+        sr.astype(jnp.int32), ut.astype(jnp.int32),
         trow.astype(jnp.int32), tcol.astype(jnp.int32), diag, tiles, b_pad,
         *carry_in,
     )
-    return out

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST precede every other import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this lowers the production step function against
@@ -17,6 +13,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -26,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import ARCH_IDS, SHAPES, cell_applicable, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (
@@ -114,7 +110,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str, *, verbose=Tru
     cfg = get_config(arch)
     t0 = time.perf_counter()
     try:
-        with compat.set_mesh(mesh):  # ambient mesh: activation constraints resolve
+        with jax.set_mesh(mesh):  # ambient mesh: activation constraints resolve
             fn, args, donate = build_step(arch, shape, mesh)
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
             t_lower = time.perf_counter() - t0
@@ -122,7 +118,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str, *, verbose=Tru
             t_compile = time.perf_counter() - t0 - t_lower
         mem = compiled.memory_analysis()
         print(mem)
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         print({k: v for k, v in cost.items() if k in ("flops", "bytes accessed")})
         # loop-aware re-analysis: XLA's cost_analysis counts while bodies once;
         # hlo_cost multiplies through known_trip_count (see repro.launch.hlo_cost)
@@ -171,6 +167,10 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str, *, verbose=Tru
 
 
 def main() -> None:
+    # before the first device query: jax fixes the device count when its
+    # backend starts (set here, not at import, so importing this module's
+    # helpers leaves the importer's devices alone)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

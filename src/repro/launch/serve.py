@@ -11,7 +11,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_reduced
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_cache, init_params
@@ -22,7 +21,7 @@ def run(arch: str, *, batch: int = 4, prompt_len: int = 32, new_tokens: int = 16
         mesh=None, quiet: bool = False):
     cfg = get_reduced(arch)
     mesh = mesh or make_host_mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         key = jax.random.PRNGKey(0)
         params = init_params(cfg, key)
         max_seq = prompt_len + new_tokens

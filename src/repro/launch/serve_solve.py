@@ -24,6 +24,8 @@ import numpy as np
 
 from repro import compat
 from repro.api import PlanOptions, SpTRSVContext  # noqa: F401  (session API)
+from repro.cache import use_compile_cache
+from repro.kernels import ops
 from repro.obs import trace as obs_trace
 from repro.service import SolveEngine
 from repro.sparse import suite
@@ -74,7 +76,8 @@ def main() -> None:
                     help="admission window before a partial batch dispatches")
     ap.add_argument("--cache-capacity", type=int, default=None,
                     help="LRU bound on the session's compiled-executor cache")
-    ap.add_argument("--block-size", type=int, default=32)
+    ap.add_argument("--block-size", type=int, default=None,
+                    help="tile size B (default: 128 on the TPU, 32 elsewhere)")
     ap.add_argument("--sched", default="levelset",
                     choices=["levelset", "dagpart", "syncfree", "auto"])
     ap.add_argument("--comm", default="zerocopy",
@@ -92,12 +95,14 @@ def main() -> None:
     ap.add_argument("--trace", default=os.environ.get(obs_trace.ENV_TRACE),
                     metavar="PATH.jsonl")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace:
         obs_trace.configure_tracing(args.trace)
 
     D = len(jax.devices())
     mesh = compat.make_mesh((D,), ("x",))
-    opts = PlanOptions(block_size=args.block_size, sched=args.sched,
+    opts = PlanOptions(block_size=args.block_size or ops.default_block_size(),
+                       sched=args.sched,
                        comm=args.comm, kernel=args.kernel)
     mats = build_patterns(args.patterns, args.n, args.levels, args.seed)
     mix = request_mix(args.requests, args.patterns, args.hot_fraction,

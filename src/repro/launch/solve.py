@@ -17,6 +17,7 @@ import numpy as np
 
 from repro import compat
 from repro.api import PlanOptions, SpTRSVContext
+from repro.cache import use_compile_cache
 from repro.core import cut_stats, metrics
 from repro.core import partition as partition_strategies
 from repro.core.analysis import level_sets
@@ -47,7 +48,8 @@ def main() -> None:
     ap.add_argument("--partition", default="taskpool",
                     choices=list(partition_strategies.STRATEGIES))
     ap.add_argument("--tasks-per-device", type=int, default=8)
-    ap.add_argument("--block-size", type=int, default=32)
+    ap.add_argument("--block-size", type=int, default=None,
+                    help="tile size B (default: 128 on the TPU, 32 elsewhere)")
     ap.add_argument("--repeats", type=int, default=10)
     ap.add_argument("--kernel", default="default",
                     choices=["default", "auto"] + list(ops.BACKENDS),
@@ -80,6 +82,7 @@ def main() -> None:
                          "saved symbolic analysis for this pattern x options "
                          "(strict-verified on load) and save it when missing")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace:
         obs_trace.configure_tracing(args.trace)
 
@@ -95,7 +98,8 @@ def main() -> None:
     D = len(jax.devices())
     mesh = compat.make_mesh((D,), ("x",))
     opts = PlanOptions(
-        block_size=args.block_size, comm=args.comm, sched=args.sched,
+        block_size=args.block_size or ops.default_block_size(),
+        comm=args.comm, sched=args.sched,
         partition=args.partition, tasks_per_device=args.tasks_per_device,
         kernel=args.kernel, rhs_hint=args.rhs_hint,
         merge_width=args.merge_width, merge_cost=args.merge_cost,

@@ -21,7 +21,6 @@ import time
 import jax
 import numpy as np
 
-from repro import compat
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_reduced
 from repro.data import SyntheticLM
@@ -39,7 +38,7 @@ def run(
 ):
     cfg = get_reduced(arch) if reduced else get_config(arch)
     mesh = mesh or make_host_mesh()
-    with compat.set_mesh(mesh):  # ambient mesh for activation sharding constraints
+    with jax.set_mesh(mesh):  # ambient mesh for activation sharding constraints
         return _run_under_mesh(
             cfg, arch, mesh, steps=steps, global_batch=global_batch,
             seq_len=seq_len, ckpt_dir=ckpt_dir, ckpt_every=ckpt_every,

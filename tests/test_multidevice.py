@@ -181,7 +181,7 @@ def test_lm_train_step_on_4_device_mesh():
         from repro.train.step import make_train_step
         from repro import compat
         mesh = compat.make_mesh((2, 2), ("data", "model"))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             cfg = get_reduced("llama3.2-1b")
             params = init_params(cfg, jax.random.PRNGKey(0))
             opt = adamw_init(params)
@@ -206,7 +206,7 @@ def test_serve_decode_on_4_device_mesh():
         from repro.serve.engine import make_decode_step, make_prefill_step
         from repro import compat
         mesh = compat.make_mesh((2, 2), ("data", "model"))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             cfg = get_reduced("llama3.2-1b")
             params = init_params(cfg, jax.random.PRNGKey(0))
             B, S = 4, 32

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.roofline import FMA_FACTOR, roofline_row
-from repro import compat
 from repro.launch.dryrun import collective_bytes
 
 
@@ -12,7 +11,7 @@ def test_xla_cpu_flops_convention():
     """cost_analysis counts 2NMK for a matmul — FMA_FACTOR must match."""
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     c = jax.jit(lambda x, y: x @ y).lower(a, a).compile()
-    flops = compat.cost_analysis(c)["flops"]
+    flops = c.cost_analysis()["flops"]
     assert abs(flops * FMA_FACTOR - 2 * 256**3) / (2 * 256**3) < 0.05
 
 
