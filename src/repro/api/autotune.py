@@ -156,13 +156,14 @@ def candidate_grid(options, n_devices: int | None = None) -> list:
     return list(itertools.product(scheds, comms, kernels))
 
 
-def tune(a, options, mesh, *, part=None, bs=None):
+def tune(a, options, mesh, *, part=None, bs=None, registry=None):
     """Resolve ``options``' auto dimensions for matrix ``a`` on ``mesh``.
 
     Returns ``(config, plan, decision, solver)`` — the winning concrete
     :class:`SolverConfig`, its plan (built on the shared partition), the
     :class:`AutoDecision`, and, when probing compiled the winner anyway, its
-    ready-to-use :class:`DistributedSolver` (else ``None``).
+    ready-to-use :class:`DistributedSolver` (else ``None``), whose
+    instruments record in ``registry`` (default: the process-wide one).
     """
     from repro.core.blocking import build_blocks, pad_rhs
     from repro.core.partition import make_partition
@@ -211,7 +212,8 @@ def tune(a, options, mesh, *, part=None, bs=None):
             for combo in combos:
                 with get_tracer().span("sptrsv.probe", sched=combo[0],
                                        comm=combo[1], kernel=combo[2]) as sp:
-                    solver = DistributedSolver(plans[combo], mesh)
+                    solver = DistributedSolver(plans[combo], mesh,
+                                               registry=registry)
                     solvers[combo] = solver
                     # the first solve pays compilation: record it separately and
                     # follow with an untimed warmup so the measured ranking never

@@ -259,7 +259,8 @@ class SpTRSVContext:
                     self._counters["auto_reuses"] += 1
                 else:
                     config, plan, decision, solver = autotune.tune(
-                        a, opts, self.mesh, bs=sym.bs, part=sym.part)
+                        a, opts, self.mesh, bs=sym.bs, part=sym.part,
+                        registry=self.registry)
                     sym.tuned[opts] = (config, decision)
                 span.set(sched=config.sched, comm=config.comm,
                          kernel=config.kernel_backend or "default")
@@ -382,7 +383,7 @@ class SpTRSVContext:
         solver = handle.solvers.get(transpose)
         if solver is None:
             solver = DistributedSolver(self.plan(handle, transpose=transpose),
-                                       self.mesh)
+                                       self.mesh, registry=self.registry)
             handle.solvers[transpose] = solver
         return solver
 

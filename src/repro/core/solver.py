@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,7 @@ from repro.core.partition import (
     STRATEGIES, Partition, make_partition, merge_levels,
 )
 from repro.kernels import ops
+from repro.obs.metrics import MetricsRegistry, get_registry
 from repro.obs.trace import get_tracer
 from repro.sparse.matrix import CSR, reverse_transpose
 from repro.kernels.superstep import (
@@ -1196,13 +1198,28 @@ class DistributedSolver:
     One instance is compiled once and invoked many times — the amortized
     regime of preconditioned Krylov loops. ``n_solves`` counts invocations
     (each multi-RHS panel counts once: one compiled solve serves R systems).
+
+    Always-on instruments in ``registry`` (default: the process-wide one):
+    ``executor.solves`` counts invocations; ``executor.h2d_bytes`` adds, per
+    invocation, the bytes of every host-resident (numpy) argument and of a
+    right-hand side staged from numpy — a device-resident argument counts 0;
+    ``executor.launch_us`` observes the launch phase (concatenate to the
+    return of the compiled call) of calls that run an already-compiled
+    program. The first call of each right-hand-side shape traces and
+    compiles; its cost is ``jit.compile_s``, not launch time.
     """
 
-    def __init__(self, plan: Plan, mesh: jax.sharding.Mesh):
+    def __init__(self, plan: Plan, mesh: jax.sharding.Mesh,
+                 registry: MetricsRegistry | None = None):
         assert mesh.devices.size == plan.n_devices, (mesh.devices.size, plan.n_devices)
         self.plan = plan
         self.mesh = mesh
         self.n_solves = 0
+        reg = registry if registry is not None else get_registry()
+        self._solves = reg.counter("executor.solves")
+        self._h2d_bytes = reg.counter("executor.h2d_bytes")
+        self._launch_us = reg.histogram("executor.launch_us")
+        self._compiled: set = set()  # (shape, dtype) of right-hand sides seen
         nb = plan.bs.nb
         D = plan.n_devices
         owner_mask = np.zeros((D, nb + 1), np.float32)
@@ -1233,11 +1250,18 @@ class DistributedSolver:
         else:
             fn = _syncfree_device_fn(plan, frontier=backend in ops.FUSED_BACKENDS)
             in_specs = (sharded,) * 5 + (repl, repl, repl, repl)
-        self._args = self._plan_args(plan)
+        self._set_args(plan)
         mapped = compat.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
         )
         self._jitted = jax.jit(mapped)
+
+    def _set_args(self, plan: Plan) -> None:
+        self._args = self._plan_args(plan)
+        # host-resident leaves are uploaded on every call; jax.Arrays are not
+        self._args_h2d_bytes = sum(
+            x.nbytes for x in jax.tree.leaves(self._args)
+            if isinstance(x, np.ndarray))
 
     def _plan_args(self, plan: Plan) -> tuple:
         if plan.config.sched in LEVELSET_SCHEDS:
@@ -1275,7 +1299,7 @@ class DistributedSolver:
                 "pattern, config, and device count as the compiled plan)"
             )
         self.plan = plan
-        self._args = self._plan_args(plan)
+        self._set_args(plan)
 
     def lower(self, R: int = 1) -> "jax.stages.Lowered":
         """The executor lowered for an R-column right-hand side (``R = 1``:
@@ -1288,22 +1312,42 @@ class DistributedSolver:
     def solve_blocks(self, b_blocks: jax.Array) -> jax.Array:
         """b_blocks: (nb, B) or a multi-RHS panel (nb, B, R) -> same shape."""
         self.n_solves += 1
-        b_pad = jnp.concatenate(
-            [b_blocks, jnp.zeros((1,) + b_blocks.shape[1:], b_blocks.dtype)]
-        )
-        return self._jitted(*self._args, b_pad)
+        self._solves.inc()
+        rhs_bytes = 0 if isinstance(b_blocks, jax.Array) else b_blocks.nbytes
+        self._h2d_bytes.inc(self._args_h2d_bytes + rhs_bytes)
+        with get_tracer().span("sptrsv.launch"):
+            t0 = time.perf_counter_ns()
+            b_pad = jnp.concatenate(
+                [b_blocks, jnp.zeros((1,) + b_blocks.shape[1:], b_blocks.dtype)]
+            )
+            x = self._jitted(*self._args, b_pad)
+            launch_ns = time.perf_counter_ns() - t0
+        key = (b_pad.shape, b_pad.dtype)
+        if key in self._compiled:
+            self._launch_us.observe(launch_ns / 1e3)
+        else:
+            self._compiled.add(key)
+        return x
 
     def solve(self, b: np.ndarray) -> np.ndarray:
         """b: (n,) or (n, R) RHS panel. Transpose plans flip row order at this
         boundary (the plan was built on ``reverse_transpose(a)``)."""
         from repro.core.blocking import pad_rhs, unpad_x
 
-        b = np.asarray(b, np.float32)
-        if self.plan.transpose:
-            b = b[::-1]
-        b_blocks = jnp.asarray(pad_rhs(b, self.plan.bs))
-        x = unpad_x(np.asarray(self.solve_blocks(b_blocks)), self.plan.bs)
-        return x[::-1].copy() if self.plan.transpose else x
+        tracer = get_tracer()
+        with tracer.span("sptrsv.stage_in"):
+            b = np.asarray(b, np.float32)
+            if self.plan.transpose:
+                b = b[::-1]
+            b_host = pad_rhs(b, self.plan.bs)
+            b_blocks = jnp.asarray(b_host)
+        self._h2d_bytes.inc(b_host.nbytes)
+        xb = self.solve_blocks(b_blocks)
+        with tracer.span("sptrsv.fetch"):  # waits for the device
+            xb = np.asarray(xb)
+        with tracer.span("sptrsv.stage_out"):
+            x = unpad_x(xb, self.plan.bs)
+            return x[::-1].copy() if self.plan.transpose else x
 
 
 def sptrsv(

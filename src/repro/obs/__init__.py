@@ -7,7 +7,8 @@ Three cooperating pieces (ISSUE 6):
   ``jax.named_scope`` annotations baked into the executors.
 * :mod:`repro.obs.metrics`     — typed counters/gauges/histograms unifying
   the solver's scattered plan-static and runtime stats behind one
-  ``snapshot()``/JSONL sink.
+  ``snapshot()``/JSONL sink, and one ``jax.monitoring`` listener, registered
+  on import, that feeds ``jit.compiles``/``jit.compile_s``.
 * :mod:`repro.obs.calibration` — measured probe timings persisted per
   (backend, bucket-width signature) and fitted back into
   ``core.costmodel.calibrate_weights`` (``REPRO_CALIBRATION=weights.json``).

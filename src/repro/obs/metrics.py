@@ -26,6 +26,8 @@ import json
 import threading
 import time
 
+import jax.monitoring
+
 
 class Counter:
     __slots__ = ("value",)
@@ -186,3 +188,33 @@ def get_registry() -> MetricsRegistry:
     """The process-wide default registry (contexts, engines, and benches
     record here unless handed their own)."""
     return _global
+
+
+# -- compile accounting ----------------------------------------------------
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    # wraps a persistent-cache load as well as a compile
+    BACKEND_COMPILE_EVENT,
+)
+_compile_lock = threading.Lock()
+
+
+def _on_duration_event(event: str, duration_secs: float, **kwargs) -> None:
+    """JAX duration listener, registered once when this module is imported:
+    ``jit.compile_s`` sums the seconds of JAX's trace, lower and
+    backend-compile (or persistent-cache load) events; ``jit.compiles``
+    counts the backend compiles and cache loads. These events fire only when
+    JAX compiles, never on the dispatch of a compiled program."""
+    if event not in COMPILE_EVENTS:
+        return
+    with _compile_lock:
+        g = _global.gauge("jit.compile_s")
+        g.set(g.value + duration_secs)
+        if event == BACKEND_COMPILE_EVENT:
+            _global.counter("jit.compiles").inc()
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration_event)

@@ -16,8 +16,6 @@ from repro.distributed.sharding import batch_specs, cache_specs, param_specs
 from repro.models.config import ModelConfig
 from repro.models.layers import vocab_pad_mask
 from repro.models.model import forward
-from repro.obs.metrics import get_registry
-from repro.obs.trace import get_tracer
 
 
 def _shard(mesh, spec_tree):
@@ -53,11 +51,9 @@ def make_prefill_step(cfg: ModelConfig, mesh, *, example_params=None,
     )
 
     def stepper(params, batch, cache):
-        with get_tracer().span("serve.prefill"):
-            get_registry().counter("serve.prefills").inc()
-            return jitted(jax.device_put(params, pspecs),
-                          jax.device_put(batch, bspecs),
-                          jax.device_put(cache, cspecs))
+        return jitted(jax.device_put(params, pspecs),
+                      jax.device_put(batch, bspecs),
+                      jax.device_put(cache, cspecs))
 
     return stepper
 
@@ -90,10 +86,8 @@ def make_decode_step(cfg: ModelConfig, mesh, *, example_params=None,
     )
 
     def stepper(params, batch, cache, pos):
-        with get_tracer().span("serve.decode", pos=int(pos)):
-            get_registry().counter("serve.decodes").inc()
-            return jitted(jax.device_put(params, pspecs),
-                          jax.device_put(batch, bspecs),
-                          jax.device_put(cache, cspecs), pos)
+        return jitted(jax.device_put(params, pspecs),
+                      jax.device_put(batch, bspecs),
+                      jax.device_put(cache, cspecs), pos)
 
     return stepper

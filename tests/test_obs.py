@@ -9,6 +9,8 @@ samples persisted, reloaded, and fitted weights applied by a probe-free
 """
 import json
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -204,6 +206,118 @@ def test_toggling_tracing_does_not_retrace():
         ctx.solve(h, b)
     ctx.solve(h, b)
     assert jitted._cache_size() == size  # same trace served all three
+
+
+# ---------------------------------------------------------------------------
+# executor phases, host-to-device bytes and compile accounting
+# ---------------------------------------------------------------------------
+
+PHASES = ("sptrsv.stage_in", "sptrsv.launch", "sptrsv.fetch",
+          "sptrsv.stage_out")
+
+
+def host_arg_bytes(solver):
+    return sum(x.nbytes for x in jax.tree.leaves(solver._args)
+               if isinstance(x, np.ndarray))
+
+
+@pytest.mark.parametrize("transpose, R", [(False, 1), (True, 1), (False, 8)],
+                         ids=["forward", "transpose", "panel8"])
+def test_solve_phase_spans_nest_under_solve_in_order(transpose, R):
+    a, b = small_problem()
+    if R > 1:
+        b = np.stack([b * (k + 1) for k in range(R)], axis=1)
+    ctx = SpTRSVContext(mesh=st.mesh1())
+    h = ctx.analyse(a)
+    with tr.trace_to() as tracer:
+        ctx.solve(h, b, transpose=transpose)
+        spans = [r for r in tracer.export() if r["type"] == "span"]
+    solve = next(r for r in spans if r["name"] == "sptrsv.solve")
+    phases = sorted((r for r in spans if r["parent"] == solve["id"]),
+                    key=lambda r: r["id"])
+    assert tuple(r["name"] for r in phases) == PHASES
+    for prev, nxt in zip(phases, phases[1:]):  # one after the other in time
+        assert nxt["t0_us"] >= prev["t0_us"] + prev["dur_us"] - 1e-3
+    assert all(r["dur_us"] <= solve["dur_us"] for r in phases)
+
+
+def test_h2d_bytes_count_host_arguments_and_padded_rhs_across_refresh():
+    a, b = small_problem()
+    reg = met.MetricsRegistry()
+    ctx = SpTRSVContext(mesh=st.mesh1(), registry=reg)
+    h = ctx.analyse(a)
+    solver = ctx.executor(h)
+    bs = solver.plan.bs
+    rhs_bytes = bs.nb * bs.B * 4  # the (nb, B) float32 blocks pad_rhs stages
+
+    def per_call():
+        before = reg.counter("executor.h2d_bytes").value
+        ctx.solve(h, b)
+        return reg.counter("executor.h2d_bytes").value - before
+
+    first = per_call()
+    assert first == host_arg_bytes(solver) + rhs_bytes > rhs_bytes
+    args_before = solver._args
+    ctx.factorize(st.dyadic(a, seed=9), h)  # re-arms the same executor
+    assert ctx.executor(h) is solver and solver._args is not args_before
+    assert per_call() == host_arg_bytes(solver) + rhs_bytes == first
+    assert reg.counter("executor.solves").value == 2
+
+
+def test_device_resident_argument_drops_out_of_h2d_bytes(monkeypatch):
+    a, b = small_problem()
+    reg = met.MetricsRegistry()
+    solver = DistributedSolver(build_plan(a, 1, PlanOptions().to_config()),
+                               st.mesh1(), registry=reg)
+    rhs_bytes = solver.plan.bs.nb * solver.plan.bs.B * 4
+    x_host = solver.solve(b)
+    host_args = reg.counter("executor.h2d_bytes").value - rhs_bytes
+    plan_args = DistributedSolver._plan_args
+    big = int(np.argmax([np.asarray(x).nbytes for x in solver._args]))
+
+    def with_device_store(self, plan):
+        args = list(plan_args(self, plan))
+        args[big] = jax.device_put(args[big])
+        return tuple(args)
+
+    monkeypatch.setattr(DistributedSolver, "_plan_args", with_device_store)
+    solver.refresh(solver.plan)
+    moved = solver._args[big].nbytes
+    before = reg.counter("executor.h2d_bytes").value
+    np.testing.assert_array_equal(solver.solve(b), x_host)
+    assert reg.counter("executor.h2d_bytes").value - before \
+        == host_args - moved + rhs_bytes
+
+
+def test_launch_us_leaves_out_the_call_that_compiles():
+    a, b = small_problem()
+    reg = met.MetricsRegistry()
+    ctx = SpTRSVContext(mesh=st.mesh1(), registry=reg)
+    h = ctx.analyse(a)
+    hist = reg.histogram("executor.launch_us")
+    ctx.solve(h, b)
+    assert hist.count == 0  # the first call of the shape traced and compiled
+    ctx.solve(h, b)
+    ctx.solve(h, np.stack([b, b], axis=1))  # a new shape compiles again
+    assert hist.count == 1 and hist.total > 0
+    ctx.solve(h, np.stack([b, b], axis=1))
+    assert hist.count == 2
+
+
+def test_jit_compile_accounting_counts_compiles_not_calls():
+    reg = met.get_registry()
+    f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 0.25)
+    x, y = (jnp.asarray(np.full((7, 3), v, np.float32)) for v in (1.0, 2.0))
+    compiles = reg.counter("jit.compiles").value
+    seconds = reg.gauge("jit.compile_s").value
+    f(x).block_until_ready()
+    assert reg.counter("jit.compiles").value > compiles
+    assert reg.gauge("jit.compile_s").value > seconds
+    compiles = reg.counter("jit.compiles").value
+    seconds = reg.gauge("jit.compile_s").value
+    f(y).block_until_ready()  # same shape: the compiled program runs
+    assert reg.counter("jit.compiles").value == compiles
+    assert reg.gauge("jit.compile_s").value == seconds
 
 
 # ---------------------------------------------------------------------------
