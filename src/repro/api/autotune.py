@@ -198,7 +198,7 @@ def tune(a, options, mesh, *, part=None, bs=None, registry=None):
 
         probe_us: dict = {}
         compile_us: dict = {}
-        solvers: dict = {}
+        best = None  # the fastest probed executor, the only one kept
         t_probe0 = time.perf_counter()
         if options.probe_solves > 0 and len(combos) > 1:
             import jax
@@ -209,12 +209,12 @@ def tune(a, options, mesh, *, part=None, bs=None, registry=None):
             b = rng.uniform(-1, 1, (a.n, R) if R > 1 else a.n).astype(np.float32)
             b_blocks = jnp.asarray(pad_rhs(b, bs))
             store = _calibration.get_store()
+            chosen = None
             for combo in combos:
                 with get_tracer().span("sptrsv.probe", sched=combo[0],
                                        comm=combo[1], kernel=combo[2]) as sp:
                     solver = DistributedSolver(plans[combo], mesh,
                                                registry=registry)
-                    solvers[combo] = solver
                     # the first solve pays compilation: record it separately and
                     # follow with an untimed warmup so the measured ranking never
                     # depends on which candidate happened to compile last
@@ -239,7 +239,11 @@ def tune(a, options, mesh, *, part=None, bs=None, registry=None):
                     solve_units=su, tile_units=tu, tile_flop_units=tf, R=R,
                     measured_us=probe_us[combo],
                 )
-            chosen = min(combos, key=lambda c: probe_us[c])
+                # every executor holds its plan's store on the mesh: keep only
+                # the fastest so far, so at most two are resident at once
+                if chosen is None or probe_us[combo] < probe_us[chosen]:
+                    chosen, best = combo, solver
+                del solver
             mode = "probed"
         else:
             chosen = min(combos, key=lambda c: scores[c])
@@ -251,4 +255,4 @@ def tune(a, options, mesh, *, part=None, bs=None, registry=None):
         tspan.set(chosen="/".join(chosen), mode=mode,
                   probe_overhead_us=overhead)
     cfg = options.to_config(sched=chosen[0], comm=chosen[1], kernel=chosen[2])
-    return cfg, plans[chosen], decision, solvers.get(chosen)
+    return cfg, plans[chosen], decision, best

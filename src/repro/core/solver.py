@@ -50,7 +50,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import warnings
 
@@ -1199,14 +1199,20 @@ class DistributedSolver:
     regime of preconditioned Krylov loops. ``n_solves`` counts invocations
     (each multi-RHS panel counts once: one compiled solve serves R systems).
 
+    The plan's arguments (schedules, tile and diagonal stores, owner masks)
+    live on the mesh: they are placed once, with the sharding of their
+    ``in_specs``, at construction and at each :meth:`refresh`, so a solve
+    uploads only its right-hand side.
+
     Always-on instruments in ``registry`` (default: the process-wide one):
     ``executor.solves`` counts invocations; ``executor.h2d_bytes`` adds, per
-    invocation, the bytes of every host-resident (numpy) argument and of a
-    right-hand side staged from numpy — a device-resident argument counts 0;
-    ``executor.launch_us`` observes the launch phase (concatenate to the
-    return of the compiled call) of calls that run an already-compiled
-    program. The first call of each right-hand-side shape traces and
-    compiles; its cost is ``jit.compile_s``, not launch time.
+    invocation, the bytes of a right-hand side staged from numpy (a
+    device-resident one counts 0); ``executor.plan_uploads`` counts
+    placements of the plan's arguments and ``executor.plan_upload_bytes``
+    adds their bytes; ``executor.launch_us`` observes the launch phase
+    (concatenate to the return of the compiled call) of calls that run an
+    already-compiled program. The first call of each right-hand-side shape
+    traces and compiles; its cost is ``jit.compile_s``, not launch time.
     """
 
     def __init__(self, plan: Plan, mesh: jax.sharding.Mesh,
@@ -1218,6 +1224,8 @@ class DistributedSolver:
         reg = registry if registry is not None else get_registry()
         self._solves = reg.counter("executor.solves")
         self._h2d_bytes = reg.counter("executor.h2d_bytes")
+        self._plan_uploads = reg.counter("executor.plan_uploads")
+        self._plan_upload_bytes = reg.counter("executor.plan_upload_bytes")
         self._launch_us = reg.histogram("executor.launch_us")
         self._compiled: set = set()  # (shape, dtype) of right-hand sides seen
         nb = plan.bs.nb
@@ -1250,6 +1258,9 @@ class DistributedSolver:
         else:
             fn = _syncfree_device_fn(plan, frontier=backend in ops.FUSED_BACKENDS)
             in_specs = (sharded,) * 5 + (repl, repl, repl, repl)
+        # one sharding per plan argument; the right-hand side is the last spec
+        self._arg_shardings = tuple(
+            NamedSharding(mesh, spec) for spec in in_specs[:-1])
         self._set_args(plan)
         mapped = compat.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
@@ -1257,11 +1268,15 @@ class DistributedSolver:
         self._jitted = jax.jit(mapped)
 
     def _set_args(self, plan: Plan) -> None:
-        self._args = self._plan_args(plan)
-        # host-resident leaves are uploaded on every call; jax.Arrays are not
-        self._args_h2d_bytes = sum(
-            x.nbytes for x in jax.tree.leaves(self._args)
-            if isinstance(x, np.ndarray))
+        # place the new arguments before dropping the old ones: a result still
+        # in flight keeps its inputs, and the old buffers go with their last
+        # reference
+        args = tuple(jax.device_put(x, s) for x, s in
+                     zip(self._plan_args(plan), self._arg_shardings,
+                         strict=True))
+        self._args = args
+        self._plan_uploads.inc()
+        self._plan_upload_bytes.inc(sum(x.nbytes for x in args))
 
     def _plan_args(self, plan: Plan) -> tuple:
         if plan.config.sched in LEVELSET_SCHEDS:
@@ -1281,7 +1296,8 @@ class DistributedSolver:
         """Swap in a numerically refreshed plan (:func:`refresh_plan`) without
         recompiling: the executor trace bakes in the *schedules*, while tile
         and diagonal values ride in as jit arguments — same shapes, same
-        compiled program, zero retrace."""
+        compiled program, zero retrace. The new arguments are placed on the
+        mesh here, once, and the old ones are released."""
         old = self.plan
         # the compiled trace bakes the old schedule in as constants, so a
         # structurally different plan would silently pair new values with the
@@ -1313,8 +1329,8 @@ class DistributedSolver:
         """b_blocks: (nb, B) or a multi-RHS panel (nb, B, R) -> same shape."""
         self.n_solves += 1
         self._solves.inc()
-        rhs_bytes = 0 if isinstance(b_blocks, jax.Array) else b_blocks.nbytes
-        self._h2d_bytes.inc(self._args_h2d_bytes + rhs_bytes)
+        if not isinstance(b_blocks, jax.Array):
+            self._h2d_bytes.inc(b_blocks.nbytes)
         with get_tracer().span("sptrsv.launch"):
             t0 = time.perf_counter_ns()
             b_pad = jnp.concatenate(

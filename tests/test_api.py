@@ -1,4 +1,7 @@
 """Session API: analyse/factorize/solve lifecycle, typed options, auto mode."""
+import gc
+import weakref
+
 import numpy as np
 import pytest
 
@@ -12,6 +15,7 @@ from repro.api import (
     pattern_key,
 )
 from repro import compat
+from repro.api import autotune
 from repro.api.autotune import candidate_grid, estimate_plan_cost
 from repro.core import DistributedSolver, SolverConfig, build_plan, refresh_plan
 from repro.krylov import matvec_lower, solve_ic0_pcg, spd_lower_from_triangular
@@ -302,6 +306,34 @@ def test_auto_probed_selection_picks_measured_min():
     b = np.random.default_rng(9).uniform(-1, 1, a.n)
     np.testing.assert_allclose(ctx.solve(h, b), reference_solve(a, b),
                                rtol=0, atol=1e-5)
+
+
+def test_auto_probe_keeps_only_the_returned_solver(monkeypatch):
+    """Each probed executor holds its plan's store on the mesh: while probing,
+    only the fastest so far outlives its turn, and once tune returns only the
+    chosen one is still referenced."""
+    made, alive_before = [], []
+
+    def alive():
+        gc.collect()
+        return [r() for r in made if r() is not None]
+
+    class Recorded(DistributedSolver):
+        def __init__(self, *args, **kwargs):
+            alive_before.append(len(alive()))
+            super().__init__(*args, **kwargs)
+            made.append(weakref.ref(self))
+
+    monkeypatch.setattr(autotune, "DistributedSolver", Recorded)
+    a = _matrix(n=200, levels=8)
+    opts = PlanOptions(sched="auto", comm="zerocopy", kernel="reference",
+                       block_size=16, probe_solves=1)
+    mesh = compat.make_mesh((1,), ("x",))
+    _, _, decision, solver = autotune.tune(a, opts, mesh)
+    assert decision.mode == "probed" and len(made) == len(decision.probe_us) > 2
+    assert alive_before == [0] + [1] * (len(made) - 1)
+    assert alive() == [solver]
+    assert solver.plan.config.sched == decision.chosen[0]
 
 
 def test_estimate_plan_cost_orders_dense_vs_bucketed_syncfree():

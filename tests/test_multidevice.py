@@ -170,6 +170,61 @@ def test_numeric_refresh_bit_identical_all_modes_on_8_devices():
     """))
 
 
+def test_plan_arguments_resident_on_4_devices():
+    """The executor places its plan arguments on the mesh once: a sharded
+    argument holds its own (1, ...) slice on each device, a replicated one the
+    whole array; a refresh re-places them and the answer follows the new
+    values. Levelset and syncfree, zero-copy."""
+    print(run_py("""
+        import numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro import compat
+        from repro.core import DistributedSolver, SolverConfig, build_plan
+        from repro.core import refresh_plan
+        from repro.core.solver import AXIS
+        from repro.obs.metrics import MetricsRegistry
+        from repro.sparse import suite
+        from repro.sparse.matrix import CSR, reference_solve
+
+        a = suite.random_levelled(400, 12, 4.0, seed=7)
+        a2 = CSR(n=a.n, row_ptr=a.row_ptr, col_idx=a.col_idx,
+                 val=a.val * (1.0 + 0.25 * np.sin(np.arange(a.nnz))))
+        b = np.random.default_rng(2).uniform(-1, 1, a.n)
+        mesh = compat.make_mesh((4,), (AXIS,))
+
+        def check(solver, n_sharded):
+            host = solver._plan_args(solver.plan)
+            specs = [x.sharding.spec for x in solver._args]
+            assert specs == [P(AXIS)] * n_sharded + [P()] * (8 - n_sharded)
+            for x, h in zip(solver._args, host):
+                assert x.committed and len(x.addressable_shards) == 4
+                for s in x.addressable_shards:
+                    lead = 1 if x.sharding.spec == P(AXIS) else h.shape[0]
+                    assert s.data.shape == (lead,) + h.shape[1:]
+                    assert np.array_equal(np.asarray(s.data), h[s.index])
+
+        def rel_err(x, a):
+            x_ref = reference_solve(a, b)
+            return np.abs(x - x_ref).max() / np.abs(x_ref).max()
+
+        for sched, n_sharded in (("levelset", 6), ("syncfree", 5)):
+            reg = MetricsRegistry()
+            cfg = SolverConfig(block_size=16, comm="zerocopy", sched=sched)
+            solver = DistributedSolver(build_plan(a, 4, cfg), mesh,
+                                       registry=reg)
+            check(solver, n_sharded)
+            assert rel_err(solver.solve(b), a) < 1e-5, sched
+            old = solver._args
+            solver.refresh(refresh_plan(solver.plan, a2))
+            assert all(x is not y for x, y in zip(solver._args, old))
+            check(solver, n_sharded)
+            assert reg.counter("executor.plan_uploads").value == 2
+            assert rel_err(solver.solve(b), a2) < 1e-5, sched
+            assert rel_err(solver.solve(b), a) > 1e-3, sched
+        print("OK")
+    """, devices=4))
+
+
 @pytest.mark.slow
 def test_lm_train_step_on_4_device_mesh():
     print(run_py("""

@@ -13,13 +13,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import strategies as st
 from repro.api import PlanOptions, SpTRSVContext
 from repro.api.autotune import plan_work_units, tune
 from repro.core.costmodel import calibrate_weights, hlo_weights
 from repro.core.partition import cut_stats
-from repro.core.solver import DistributedSolver, build_plan, dispatch_stats
+from repro.core.blocking import pad_rhs
+from repro.core.solver import (
+    AXIS, DistributedSolver, build_plan, dispatch_stats,
+)
 from repro.kernels import ops
 from repro.obs import calibration as cal
 from repro.obs import metrics as met
@@ -216,11 +220,6 @@ PHASES = ("sptrsv.stage_in", "sptrsv.launch", "sptrsv.fetch",
           "sptrsv.stage_out")
 
 
-def host_arg_bytes(solver):
-    return sum(x.nbytes for x in jax.tree.leaves(solver._args)
-               if isinstance(x, np.ndarray))
-
-
 @pytest.mark.parametrize("transpose, R", [(False, 1), (True, 1), (False, 8)],
                          ids=["forward", "transpose", "panel8"])
 def test_solve_phase_spans_nest_under_solve_in_order(transpose, R):
@@ -242,11 +241,18 @@ def test_solve_phase_spans_nest_under_solve_in_order(transpose, R):
 
 
 def test_h2d_bytes_count_host_arguments_and_padded_rhs_across_refresh():
+    """The plan's arguments are placed once per construction and refresh; a
+    solve stages only its padded right-hand side."""
     a, b = small_problem()
     reg = met.MetricsRegistry()
     ctx = SpTRSVContext(mesh=st.mesh1(), registry=reg)
     h = ctx.analyse(a)
+    uploads = reg.counter("executor.plan_uploads")
+    upload_bytes = reg.counter("executor.plan_upload_bytes")
+    assert (uploads.value, upload_bytes.value) == (0, 0)
     solver = ctx.executor(h)
+    store_bytes = sum(np.asarray(x).nbytes for x in solver._args)
+    assert (uploads.value, upload_bytes.value) == (1, store_bytes)
     bs = solver.plan.bs
     rhs_bytes = bs.nb * bs.B * 4  # the (nb, B) float32 blocks pad_rhs stages
 
@@ -255,38 +261,38 @@ def test_h2d_bytes_count_host_arguments_and_padded_rhs_across_refresh():
         ctx.solve(h, b)
         return reg.counter("executor.h2d_bytes").value - before
 
-    first = per_call()
-    assert first == host_arg_bytes(solver) + rhs_bytes > rhs_bytes
+    assert per_call() == rhs_bytes
+    assert per_call() == rhs_bytes
+    assert (uploads.value, upload_bytes.value) == (1, store_bytes)
     args_before = solver._args
     ctx.factorize(st.dyadic(a, seed=9), h)  # re-arms the same executor
     assert ctx.executor(h) is solver and solver._args is not args_before
-    assert per_call() == host_arg_bytes(solver) + rhs_bytes == first
-    assert reg.counter("executor.solves").value == 2
+    assert (uploads.value, upload_bytes.value) == (2, 2 * store_bytes)
+    assert per_call() == rhs_bytes
+    assert (uploads.value, upload_bytes.value) == (2, 2 * store_bytes)
+    assert reg.counter("executor.solves").value == 3
 
 
-def test_device_resident_argument_drops_out_of_h2d_bytes(monkeypatch):
+def test_device_resident_argument_drops_out_of_h2d_bytes():
+    """Every plan argument is a committed jax.Array with its spec's sharding,
+    and the resident arguments give bitwise the answer of host copies."""
     a, b = small_problem()
     reg = met.MetricsRegistry()
     solver = DistributedSolver(build_plan(a, 1, PlanOptions().to_config()),
                                st.mesh1(), registry=reg)
-    rhs_bytes = solver.plan.bs.nb * solver.plan.bs.B * 4
-    x_host = solver.solve(b)
-    host_args = reg.counter("executor.h2d_bytes").value - rhs_bytes
-    plan_args = DistributedSolver._plan_args
-    big = int(np.argmax([np.asarray(x).nbytes for x in solver._args]))
-
-    def with_device_store(self, plan):
-        args = list(plan_args(self, plan))
-        args[big] = jax.device_put(args[big])
-        return tuple(args)
-
-    monkeypatch.setattr(DistributedSolver, "_plan_args", with_device_store)
-    solver.refresh(solver.plan)
-    moved = solver._args[big].nbytes
-    before = reg.counter("executor.h2d_bytes").value
-    np.testing.assert_array_equal(solver.solve(b), x_host)
-    assert reg.counter("executor.h2d_bytes").value - before \
-        == host_args - moved + rhs_bytes
+    plan = solver.plan
+    assert not solver._streamed
+    specs = (P(AXIS),) * 6 + (P(), P())  # levelset: diag and ex replicated
+    assert len(solver._args) == len(specs)
+    for x, spec in zip(solver._args, specs):
+        assert isinstance(x, jax.Array) and x.committed
+        assert x.sharding == NamedSharding(solver.mesh, spec)
+    b_blocks = jnp.asarray(pad_rhs(b.astype(np.float32), plan.bs))
+    b_pad = jnp.concatenate([b_blocks, jnp.zeros((1, plan.bs.B), jnp.float32)])
+    x_host = solver._jitted(*[np.asarray(x) for x in solver._args], b_pad)
+    np.testing.assert_array_equal(np.asarray(solver.solve_blocks(b_blocks)),
+                                  np.asarray(x_host))
+    assert reg.counter("executor.h2d_bytes").value == 0  # b_blocks is resident
 
 
 def test_launch_us_leaves_out_the_call_that_compiles():
