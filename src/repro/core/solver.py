@@ -135,7 +135,8 @@ class Plan:
     config: SolverConfig
     n_devices: int
     n_levels: int
-    # replicated
+    # replicated (the levelset executors shard the diagonal by owner instead:
+    # see device_diag and solve_slots)
     diag: np.ndarray  # (nb+1, B, B) identity at pad slot
     owner: np.ndarray  # (nb+1,) int32, -1 at pad
     indeg: np.ndarray  # (nb+1,) int32 tile in-degree per block row
@@ -147,6 +148,7 @@ class Plan:
     buckets: tuple  # ((ws, wu, we), ...) level widths, small set (<= MAX_BUCKETS)
     # sharded by leading device axis
     solve_rows: np.ndarray  # (D, S) ragged owned rows per level, pad -1 (levelset)
+    solve_slots: np.ndarray  # (D, S) device_diag slot of each solve_rows entry, pad MLR
     upd_tiles: np.ndarray  # (D, U) ragged local tile ids per level, pad ML (levelset)
     local_rows: np.ndarray  # (D, MLR) owned rows, pad nb (syncfree)
     tile_row: np.ndarray  # (D, ML+1) dest block-row per local tile, pad nb
@@ -259,6 +261,32 @@ def _tiles_by_device(bs: BlockStructure, part: Partition, D: int) -> list:
     into exactly the slots the compiled executors index."""
     tile_dev = part.owner[bs.off_cols]
     return [np.nonzero(tile_dev == d)[0] for d in range(D)]
+
+
+def local_slots(solve_rows: np.ndarray, local_rows: np.ndarray, nb: int
+                ) -> np.ndarray:
+    """(D, S) slot of each scheduled row in its device's diagonal store
+    (:func:`device_diag`): a row's rank among the rows its owner holds, in
+    ``local_rows`` order; pad entries (-1) read the pad slot ``MLR``. On one
+    device a row's slot is its row id."""
+    D, MLR = local_rows.shape
+    slot = np.full(nb + 1, MLR, dtype=np.int32)
+    for d in range(D):
+        own = local_rows[d][local_rows[d] < nb]
+        slot[own] = np.arange(own.shape[0], dtype=np.int32)
+    return slot[np.where(solve_rows < 0, nb, solve_rows)]
+
+
+def device_diag(plan: Plan) -> np.ndarray:
+    """(D, MLR+1, B, B) per-device diagonal stores for the levelset switch
+    executors: slot ``k`` of device ``d`` holds the diagonal tile of
+    ``local_rows[d, k]``, its ``k``-th owned block row, and the slots past its
+    rows, the pad slot ``MLR`` among them, hold the identity. Each device
+    holds the block rows it solves and no others; on one device the store is
+    ``plan.diag``."""
+    D, nb = plan.n_devices, plan.bs.nb
+    pad = np.full((D, 1), nb, dtype=plan.local_rows.dtype)
+    return plan.diag[np.concatenate([plan.local_rows, pad], axis=1)]
 
 
 def build_plan(
@@ -401,7 +429,7 @@ def _build_plan(
         ex_rows[lvl_off[t, 2]: lvl_off[t, 2] + e.shape[0]] = e
     ex_boundary = b_rows.astype(np.int32) if b_rows.size else np.full((1,), nb, dtype=np.int32)
 
-    # --- syncfree plan ---
+    # --- owned rows (the syncfree plan and the per-device diagonal stores) ---
     per_dev_rows = [np.nonzero(part.owner == d)[0] for d in range(D)]
     MLR = max((r.shape[0] for r in per_dev_rows), default=1) or 1
     local_rows = np.full((D, MLR), nb, dtype=np.int32)
@@ -412,7 +440,8 @@ def _build_plan(
         bs=bs, part=part, config=config, n_devices=D, n_levels=T,
         diag=diag, owner=owner, indeg=indeg, ex_rows=ex_rows,
         ex_boundary=ex_boundary, lvl_off=lvl_off, lvl_bucket=lvl_bucket,
-        buckets=buckets, solve_rows=solve_rows, upd_tiles=upd_tiles,
+        buckets=buckets, solve_rows=solve_rows,
+        solve_slots=local_slots(solve_rows, local_rows, nb), upd_tiles=upd_tiles,
         local_rows=local_rows, tile_row=tile_row, tile_col=tile_col, tiles=tiles,
         transpose=transpose,
         frontier_caps=(max(1, int(ws.max())) if T else 1,
@@ -452,9 +481,13 @@ def refresh_plan(plan: Plan, a: CSR) -> Plan:
 
 
 def _compact_level_body(
-    plan: Plan, sr, ut, trow, tcol, tiles, diag, b_pad, ex, split_delta=False
+    plan: Plan, sr, ss, ut, trow, tcol, tiles, diag, b_pad, ex, split_delta=False
 ):
     """Return the compacted superstep body shared by all levelset executors.
+
+    ``sr`` names each scheduled row by its global id (``b_pad``, the carries
+    and ``x`` are indexed by row); ``ss`` names its slot in ``diag``, the
+    device's own diagonal store (:func:`device_diag`).
 
     One branch is built per occurring width-bucket combo and dispatched with
     ``lax.switch`` on the level's bucket id; each branch slices its level's
@@ -495,12 +528,13 @@ def _compact_level_body(
             if w_s > 0:
                 with jax.named_scope("sptrsv.level_solve"):
                     rows = jax.lax.dynamic_slice(sr, (off[t, 0],), (w_s,))
+                    slots = jax.lax.dynamic_slice(ss, (off[t, 0],), (w_s,))
                     safe = jnp.where(rows < 0, nb, rows)
                     rhs = b_pad[safe] - acc[safe]
                     if split_delta:
                         rhs = rhs - delta[safe]
                     xs = ops.batched_block_trsv(
-                        diag[safe], rhs, backend=cfg.kernel_backend
+                        diag[slots], rhs, backend=cfg.kernel_backend
                     )
                     x = x.at[safe].set(
                         jnp.where(ops.bcast_trailing(rows >= 0, xs), xs, x[safe])
@@ -910,13 +944,15 @@ def solve_local(plan: Plan, b_blocks: jax.Array) -> jax.Array:
             stream=streamed, solve_widths=sw, upd_widths=uw,
         )
         return from_rows(x, b_pad.ndim == 3)[:nb]
-    diag = jnp.asarray(plan.diag)
+    diag = jnp.asarray(device_diag(plan)[0])
     sr = jnp.asarray(plan.solve_rows[0])
+    ss = jnp.asarray(plan.solve_slots[0])
     ut = jnp.asarray(plan.upd_tiles[0])
     trow = jnp.asarray(plan.tile_row[0])
     tcol = jnp.asarray(plan.tile_col[0])
     tiles = jnp.asarray(plan.tiles[0])
-    body = _compact_level_body(plan, sr, ut, trow, tcol, tiles, diag, b_pad, ex=None)
+    body = _compact_level_body(plan, sr, ss, ut, trow, tcol, tiles, diag, b_pad,
+                               ex=None)
     acc0 = jnp.zeros_like(b_pad)
     _, x = jax.lax.fori_loop(0, plan.n_levels, body, (acc0, acc0))
     return x[:nb]
@@ -936,12 +972,12 @@ def _levelset_device_fn(plan: Plan):
         cfg.comm == "zerocopy" and plan.n_devices > 1 and plan.n_boundary_rows > 0
     )
 
-    def fn(sr, ut, trow, tcol, tiles, owner_mask, diag, ex, b_pad):
+    def fn(sr, ss, ut, trow, tcol, tiles, owner_mask, diag, ex, b_pad):
         # leading device dim of sharded operands is 1 inside shard_map
-        sr, ut = sr[0], ut[0]
+        sr, ss, ut = sr[0], ss[0], ut[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
         body = _compact_level_body(
-            plan, sr, ut, trow, tcol, tiles, diag, b_pad,
+            plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_pad,
             ex=ex if has_ex else None,
         )
         acc0 = jnp.zeros_like(b_pad)
@@ -964,12 +1000,13 @@ def _levelset_unified_device_fn(plan: Plan):
     so = step_offsets(plan)
     n_steps = plan.n_supersteps
 
-    def fn(sr, ut, trow, tcol, tiles, owner_mask, diag, ex, b_pad):
+    def fn(sr, ss, ut, trow, tcol, tiles, owner_mask, diag, ex, b_pad):
         del ex  # unified ignores the packed exchange schedule
-        sr, ut = sr[0], ut[0]
+        sr, ss, ut = sr[0], ss[0], ut[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
         step = _compact_level_body(
-            plan, sr, ut, trow, tcol, tiles, diag, b_pad, ex=None, split_delta=True
+            plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_pad, ex=None,
+            split_delta=True,
         )
         stp = jnp.asarray(so.astype(np.int32))
 
@@ -1204,6 +1241,11 @@ class DistributedSolver:
     ``in_specs``, at construction and at each :meth:`refresh`, so a solve
     uploads only its right-hand side.
 
+    The levelset switch executors give each device only the diagonal tiles
+    of the block rows it owns (:func:`device_diag`, sharded like the tiles);
+    the fused megakernel and the syncfree executor take the whole diagonal,
+    replicated.
+
     Always-on instruments in ``registry`` (default: the process-wide one):
     ``executor.solves`` counts invocations; ``executor.h2d_bytes`` adds, per
     invocation, the bytes of a right-hand side staged from numpy (a
@@ -1213,6 +1255,10 @@ class DistributedSolver:
     (concatenate to the return of the compiled call) of calls that run an
     already-compiled program. The first call of each right-hand-side shape
     traces and compiles; its cost is ``jit.compile_s``, not launch time.
+    ``executor.exchanges`` and ``executor.exchange_bytes`` add, per
+    invocation, the plan's boundary exchanges (``dispatch_stats``; one per
+    superstep for syncfree) and their payload (``comm_bytes_per_solve``
+    times the right-hand side's columns); both stay 0 on one device.
     """
 
     def __init__(self, plan: Plan, mesh: jax.sharding.Mesh,
@@ -1227,6 +1273,12 @@ class DistributedSolver:
         self._plan_uploads = reg.counter("executor.plan_uploads")
         self._plan_upload_bytes = reg.counter("executor.plan_upload_bytes")
         self._launch_us = reg.histogram("executor.launch_us")
+        self._exchanges = reg.counter("executor.exchanges")
+        self._exchange_bytes = reg.counter("executor.exchange_bytes")
+        self._n_exchanges = (plan.n_supersteps if plan.config.sched == "syncfree"
+                             and plan.comm_bytes_per_solve
+                             else dispatch_stats(plan)["exchanges"])
+        self._ex_bytes = plan.comm_bytes_per_solve
         self._compiled: set = set()  # (shape, dtype) of right-hand sides seen
         nb = plan.bs.nb
         D = plan.n_devices
@@ -1239,22 +1291,25 @@ class DistributedSolver:
         repl = P()
         backend = ops.executor_backend(plan.config.kernel_backend)
         self._streamed = fused_streaming(plan)
-        if plan.config.sched in LEVELSET_SCHEDS:
-            if backend in ops.FUSED_BACKENDS:
-                fn = _fused_levelset_device_fn(plan)
-            else:
-                # unified with an empty cut degrades to the exchange-free
-                # executor: the dense per-level psums would only move zeros
-                fn = (
-                    _levelset_device_fn(plan)
-                    if plan.config.comm == "zerocopy" or D == 1
-                    or plan.n_boundary_rows == 0
-                    else _levelset_unified_device_fn(plan)
-                )
+        self._fused = (plan.config.sched in LEVELSET_SCHEDS
+                       and backend in ops.FUSED_BACKENDS)
+        if self._fused:
+            fn = _fused_levelset_device_fn(plan)
             # streaming swaps the replicated diag for the per-device
             # schedule-ordered store, which is sharded like the tiles
             diag_spec = sharded if self._streamed else repl
             in_specs = (sharded,) * 6 + (diag_spec, repl, repl)
+        elif plan.config.sched in LEVELSET_SCHEDS:
+            # unified with an empty cut degrades to the exchange-free
+            # executor: the dense per-level psums would only move zeros
+            fn = (
+                _levelset_device_fn(plan)
+                if plan.config.comm == "zerocopy" or D == 1
+                or plan.n_boundary_rows == 0
+                else _levelset_unified_device_fn(plan)
+            )
+            # the diagonal store is per device, sharded like the tiles
+            in_specs = (sharded,) * 8 + (repl, repl)
         else:
             fn = _syncfree_device_fn(plan, frontier=backend in ops.FUSED_BACKENDS)
             in_specs = (sharded,) * 5 + (repl, repl, repl, repl)
@@ -1279,15 +1334,20 @@ class DistributedSolver:
         self._plan_upload_bytes.inc(sum(x.nbytes for x in args))
 
     def _plan_args(self, plan: Plan) -> tuple:
-        if plan.config.sched in LEVELSET_SCHEDS:
+        # the stores are rebuilt from the plan here, so a refresh re-places
+        # them with its new values
+        if self._fused:
             diag, tiles = plan.diag, plan.tiles
             if self._streamed:
-                # schedule-ordered HBM stores; recomputed here on every
-                # refresh so re-armed values reach the streamed kernel too
+                # schedule-ordered HBM stores
                 diag, tiles = streamed_stores(plan)
             return (plan.solve_rows, plan.upd_tiles, plan.tile_row,
                     plan.tile_col, tiles, self._owner_mask, diag,
                     plan.ex_rows)
+        if plan.config.sched in LEVELSET_SCHEDS:
+            return (plan.solve_rows, plan.solve_slots, plan.upd_tiles,
+                    plan.tile_row, plan.tile_col, plan.tiles,
+                    self._owner_mask, device_diag(plan), plan.ex_rows)
         return (plan.local_rows, plan.tile_row, plan.tile_col,
                 plan.tiles, self._owner_mask, plan.diag, plan.indeg,
                 plan.ex_boundary)
@@ -1329,6 +1389,9 @@ class DistributedSolver:
         """b_blocks: (nb, B) or a multi-RHS panel (nb, B, R) -> same shape."""
         self.n_solves += 1
         self._solves.inc()
+        self._exchanges.inc(self._n_exchanges)
+        R = b_blocks.shape[2] if b_blocks.ndim == 3 else 1
+        self._exchange_bytes.inc(self._ex_bytes * R)
         if not isinstance(b_blocks, jax.Array):
             self._h2d_bytes.inc(b_blocks.nbytes)
         with get_tracer().span("sptrsv.launch"):

@@ -46,7 +46,7 @@ import numpy as np
 
 from repro.core.blocking import BlockStructure
 from repro.core.partition import Partition
-from repro.core.solver import Plan, SolverConfig, refresh_plan
+from repro.core.solver import Plan, SolverConfig, local_slots, refresh_plan
 from repro.obs.trace import get_tracer
 from repro.sparse.matrix import CSR
 
@@ -249,7 +249,9 @@ class PlanStore:
             ex_boundary=arrs["ex_boundary"], lvl_off=arrs["lvl_off"],
             lvl_bucket=arrs["lvl_bucket"],
             buckets=tuple(tuple(int(v) for v in b) for b in meta["buckets"]),
-            solve_rows=arrs["solve_rows"], upd_tiles=arrs["upd_tiles"],
+            solve_rows=arrs["solve_rows"],
+            solve_slots=local_slots(arrs["solve_rows"], arrs["local_rows"], nb),
+            upd_tiles=arrs["upd_tiles"],
             local_rows=arrs["local_rows"], tile_row=arrs["tile_row"],
             tile_col=arrs["tile_col"],
             tiles=np.zeros((D, ML1, B, B), np.float32),

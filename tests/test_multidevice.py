@@ -11,6 +11,7 @@ import textwrap
 import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+TESTS = os.path.dirname(os.path.abspath(__file__))
 
 
 def run_py(code: str, devices: int = 8) -> str:
@@ -195,7 +196,7 @@ def test_plan_arguments_resident_on_4_devices():
         def check(solver, n_sharded):
             host = solver._plan_args(solver.plan)
             specs = [x.sharding.spec for x in solver._args]
-            assert specs == [P(AXIS)] * n_sharded + [P()] * (8 - n_sharded)
+            assert specs == [P(AXIS)] * n_sharded + [P()] * (len(host) - n_sharded)
             for x, h in zip(solver._args, host):
                 assert x.committed and len(x.addressable_shards) == 4
                 for s in x.addressable_shards:
@@ -207,7 +208,7 @@ def test_plan_arguments_resident_on_4_devices():
             x_ref = reference_solve(a, b)
             return np.abs(x - x_ref).max() / np.abs(x_ref).max()
 
-        for sched, n_sharded in (("levelset", 6), ("syncfree", 5)):
+        for sched, n_sharded in (("levelset", 8), ("syncfree", 5)):
             reg = MetricsRegistry()
             cfg = SolverConfig(block_size=16, comm="zerocopy", sched=sched)
             solver = DistributedSolver(build_plan(a, 4, cfg), mesh,
@@ -221,6 +222,132 @@ def test_plan_arguments_resident_on_4_devices():
             assert reg.counter("executor.plan_uploads").value == 2
             assert rel_err(solver.solve(b), a2) < 1e-5, sched
             assert rel_err(solver.solve(b), a) > 1e-3, sched
+        print("OK")
+    """, devices=4))
+
+
+@pytest.mark.parametrize("devices", [4, 8])
+def test_diagonal_store_per_device(devices):
+    """The levelset executor gives each device the diagonal tiles of the
+    block rows it owns, in order, and identity tiles past them (one pad on
+    the fullest device). Forward, transpose and R=8 solves are bitwise those
+    of the same program fed the whole diagonal on every device (the
+    replicated layout), on dyadic values; a refresh re-places the store."""
+    print(run_py(f"""
+        import sys
+        sys.path.insert(0, {TESTS!r})
+        import numpy as np
+        import strategies as st
+        from repro import compat
+        from repro.core import DistributedSolver, SolverConfig, build_plan
+        from repro.core import refresh_plan
+        from repro.core.blocking import pad_rhs
+        from repro.core.solver import AXIS, device_diag
+        from repro.obs.metrics import MetricsRegistry
+        from repro.sparse import suite
+
+        D = {devices}
+        a = st.dyadic(suite.random_levelled(400, 6, 4.0, seed=7))
+        a2 = st.dyadic(a, seed=9)
+        mesh = compat.make_mesh((D,), (AXIS,))
+        cfg = SolverConfig(block_size=16, comm="zerocopy", partition="malleable")
+
+        def check_store(solver):
+            plan = solver.plan
+            nb, B = plan.bs.nb, plan.bs.B
+            owned = [np.nonzero(plan.part.owner == d)[0] for d in range(D)]
+            MLR = max(r.shape[0] for r in owned)
+            store = solver._args[7]
+            assert store.shape == (D, MLR + 1, B, B)
+            eye = np.eye(B, dtype=np.float32)
+            for s in store.addressable_shards:
+                d = s.index[0].start
+                held = np.asarray(s.data)[0]
+                assert held.shape == (MLR + 1, B, B)
+                k = owned[d].shape[0]
+                assert np.array_equal(held[:k], plan.diag[owned[d]])
+                assert (held[k:] == eye).all()
+            # each scheduled row reads its own tile from its device's store
+            for d in range(D):
+                rows = plan.solve_rows[d]
+                ok = rows >= 0
+                assert np.array_equal(plan.diag[np.where(ok, rows, nb)],
+                                      device_diag(plan)[d][plan.solve_slots[d]])
+
+        def replicated(solver, b_blocks):
+            plan = solver.plan
+            host = list(solver._plan_args(plan))
+            host[1] = np.where(plan.solve_rows < 0, plan.bs.nb, plan.solve_rows)
+            host[7] = np.stack([plan.diag] * D)
+            b_pad = np.concatenate([b_blocks, np.zeros((1,) + b_blocks.shape[1:],
+                                                       np.float32)])
+            return np.asarray(solver._jitted(*host, b_pad))
+
+        for transpose in (False, True):
+            reg = MetricsRegistry()
+            solver = DistributedSolver(build_plan(a, D, cfg, transpose=transpose),
+                                       mesh, registry=reg)
+            assert solver.plan.n_boundary_rows > 0
+            check_store(solver)
+            for R in (1, 8):
+                shape = (a.n,) if R == 1 else (a.n, R)
+                b = st.dyadic_rhs(a.n * R, seed=R).reshape(shape)
+                bb = pad_rhs(b, solver.plan.bs)
+                x = np.asarray(solver.solve_blocks(bb))
+                assert np.array_equal(x, replicated(solver, bb)), (transpose, R)
+            old = solver._args[7]
+            b = st.dyadic_rhs(a.n, seed=3)
+            x_old = solver.solve(b)
+            solver.refresh(refresh_plan(solver.plan, a2))
+            assert solver._args[7] is not old
+            check_store(solver)
+            assert not np.array_equal(np.asarray(old), np.asarray(solver._args[7]))
+            bb = pad_rhs(b, solver.plan.bs)
+            assert np.array_equal(np.asarray(solver.solve_blocks(bb)),
+                                  replicated(solver, bb))
+            assert not np.array_equal(solver.solve(b), x_old)
+        print("OK")
+    """, devices=devices))
+
+
+def test_exchange_counters_follow_the_plan():
+    """``executor.exchanges``/``executor.exchange_bytes`` add the plan's
+    exchanges (``dispatch_stats``) and payload (``comm_bytes_per_solve``, times
+    R for a panel) per call, forward and transpose; a syncfree plan exchanges
+    once a superstep. On one device both stay 0."""
+    print(run_py("""
+        import numpy as np
+        import jax
+        from repro import compat
+        from repro.core import DistributedSolver, SolverConfig, build_plan
+        from repro.core import dispatch_stats
+        from repro.core.solver import AXIS
+        from repro.obs.metrics import MetricsRegistry
+        from repro.sparse import suite
+
+        a = suite.random_levelled(400, 12, 4.0, seed=7)
+        b = np.random.default_rng(2).uniform(-1, 1, a.n)
+        B = np.random.default_rng(3).uniform(-1, 1, (a.n, 8))
+        for D in (4, 1):
+            mesh = compat.make_mesh((D,), (AXIS,), devices=jax.devices()[:D])
+            for sched, transpose in (("levelset", False), ("levelset", True),
+                                     ("syncfree", False)):
+                cfg = SolverConfig(block_size=16, comm="zerocopy",
+                                   partition="malleable", sched=sched)
+                reg = MetricsRegistry()
+                plan = build_plan(a, D, cfg, transpose=transpose)
+                solver = DistributedSolver(plan, mesh, registry=reg)
+                n_ex = (dispatch_stats(plan)["exchanges"] if sched == "levelset"
+                        else plan.n_supersteps * (D > 1))
+                ex_bytes = plan.comm_bytes_per_solve
+                assert (n_ex > 0 and ex_bytes > 0) == (D > 1), (D, n_ex, ex_bytes)
+                for _ in range(3):
+                    solver.solve(b)
+                solver.solve(B)
+                assert reg.counter("executor.solves").value == 4
+                assert reg.counter("executor.exchanges").value == 4 * n_ex
+                assert reg.counter("executor.exchange_bytes").value == (
+                    3 * ex_bytes + 8 * ex_bytes)
         print("OK")
     """, devices=4))
 

@@ -282,7 +282,7 @@ def test_device_resident_argument_drops_out_of_h2d_bytes():
                                st.mesh1(), registry=reg)
     plan = solver.plan
     assert not solver._streamed
-    specs = (P(AXIS),) * 6 + (P(), P())  # levelset: diag and ex replicated
+    specs = (P(AXIS),) * 8 + (P(),)  # levelset: ex replicated, the diagonal per device
     assert len(solver._args) == len(specs)
     for x, spec in zip(solver._args, specs):
         assert isinstance(x, jax.Array) and x.committed
