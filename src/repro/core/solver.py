@@ -480,6 +480,18 @@ def refresh_plan(plan: Plan, a: CSR) -> Plan:
 # ---------------------------------------------------------------------------
 
 
+def _rows_in(b_pad: jax.Array) -> jax.Array:
+    """The levelset executors' carry layout: a ``(nb+1, B, R)`` panel becomes
+    ``(nb+1, R, B)`` row panels, once, inside the compiled program, with B on
+    the lanes and R on the sublanes; a vector stays as it is."""
+    return to_rows(b_pad) if b_pad.ndim == 3 else b_pad
+
+
+def _rows_out(x: jax.Array, multi: bool) -> jax.Array:
+    """Inverse of :func:`_rows_in`."""
+    return from_rows(x, True) if multi else x
+
+
 def _compact_level_body(
     plan: Plan, sr, ss, ut, trow, tcol, tiles, diag, b_pad, ex, split_delta=False
 ):
@@ -495,6 +507,11 @@ def _compact_level_body(
     solve/update/exchange work scales with the level's bucket instead of the
     global max. ``ex is None`` disables the zero-copy boundary pull.
 
+    A rank-2 ``b_pad`` and carries are ``(nb+1, B)`` vectors; a rank-3 one
+    holds ``(nb+1, R, B)`` row panels (:func:`_rows_in`), solved and
+    multiplied by the row-panel kernels, so no level changes a carry's
+    layout.
+
     Carry is ``(acc, x)``, or ``(acc, delta, x)`` with ``split_delta`` — then
     tile updates land in ``delta`` (the unified executor's not-yet-exchanged
     contributions; incompatible with ``ex``) while solves read ``acc + delta``:
@@ -509,6 +526,7 @@ def _compact_level_body(
     nb = plan.bs.nb
     off = jnp.asarray(plan.lvl_off)
     bucket_id = jnp.asarray(plan.lvl_bucket)
+    panel = b_pad.ndim == 3
 
     def make_branch(w_s: int, w_u: int, w_e: int):
         def branch(t, carry):
@@ -533,19 +551,32 @@ def _compact_level_body(
                     rhs = b_pad[safe] - acc[safe]
                     if split_delta:
                         rhs = rhs - delta[safe]
-                    xs = ops.batched_block_trsv(
-                        diag[slots], rhs, backend=cfg.kernel_backend
-                    )
-                    x = x.at[safe].set(
-                        jnp.where(ops.bcast_trailing(rows >= 0, xs), xs, x[safe])
-                    )
+                    if panel:
+                        xs = ops.batched_block_trsv_rows(
+                            diag[slots], rhs, backend=cfg.kernel_backend)
+                        # pad rows index past the carry and are dropped: the
+                        # scatter reads nothing of x, so XLA updates it in
+                        # place (a where over x[safe] makes it copy the carry)
+                        x = x.at[jnp.where(rows < 0, nb + 1, rows)].set(
+                            xs, mode="drop")
+                    else:
+                        xs = ops.batched_block_trsv(
+                            diag[slots], rhs, backend=cfg.kernel_backend
+                        )
+                        x = x.at[safe].set(
+                            jnp.where(ops.bcast_trailing(rows >= 0, xs), xs, x[safe])
+                        )
             if w_u > 0:
                 with jax.named_scope("sptrsv.tile_update"):
                     tids = jax.lax.dynamic_slice(ut, (off[t, 1],), (w_u,))
-                    prods = ops.batched_block_gemv(
-                        tiles[tids], x[tcol[tids]], backend=cfg.kernel_backend,
-                        group=cfg.gemv_group,
-                    )
+                    if panel:
+                        prods = ops.batched_block_gemv_rows(
+                            tiles[tids], x[tcol[tids]], backend=cfg.kernel_backend)
+                    else:
+                        prods = ops.batched_block_gemv(
+                            tiles[tids], x[tcol[tids]], backend=cfg.kernel_backend,
+                            group=cfg.gemv_group,
+                        )
                     if split_delta:
                         delta = delta.at[trow[tids]].add(prods)
                     else:
@@ -951,11 +982,12 @@ def solve_local(plan: Plan, b_blocks: jax.Array) -> jax.Array:
     trow = jnp.asarray(plan.tile_row[0])
     tcol = jnp.asarray(plan.tile_col[0])
     tiles = jnp.asarray(plan.tiles[0])
-    body = _compact_level_body(plan, sr, ss, ut, trow, tcol, tiles, diag, b_pad,
+    b_in = _rows_in(b_pad)
+    body = _compact_level_body(plan, sr, ss, ut, trow, tcol, tiles, diag, b_in,
                                ex=None)
-    acc0 = jnp.zeros_like(b_pad)
+    acc0 = jnp.zeros_like(b_in)
     _, x = jax.lax.fori_loop(0, plan.n_levels, body, (acc0, acc0))
-    return x[:nb]
+    return _rows_out(x[:nb], b_pad.ndim == 3)
 
 
 # ---------------------------------------------------------------------------
@@ -976,17 +1008,18 @@ def _levelset_device_fn(plan: Plan):
         # leading device dim of sharded operands is 1 inside shard_map
         sr, ss, ut = sr[0], ss[0], ut[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
+        b_in = _rows_in(b_pad)
         body = _compact_level_body(
-            plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_pad,
+            plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_in,
             ex=ex if has_ex else None,
         )
-        acc0 = jnp.zeros_like(b_pad)
+        acc0 = jnp.zeros_like(b_in)
         _, x = jax.lax.fori_loop(0, T, body, (acc0, acc0))
         with jax.named_scope("sptrsv.gather"):
             xg = x * ops.bcast_trailing(owner_mask, x)
             if plan.n_devices > 1:
                 xg = jax.lax.psum(xg, AXIS)
-        return xg[:nb]
+        return _rows_out(xg[:nb], b_pad.ndim == 3)
 
     return fn
 
@@ -1004,8 +1037,9 @@ def _levelset_unified_device_fn(plan: Plan):
         del ex  # unified ignores the packed exchange schedule
         sr, ss, ut = sr[0], ss[0], ut[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
+        b_in = _rows_in(b_pad)
         step = _compact_level_body(
-            plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_pad, ex=None,
+            plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_in, ex=None,
             split_delta=True,
         )
         stp = jnp.asarray(so.astype(np.int32))
@@ -1020,10 +1054,11 @@ def _levelset_unified_device_fn(plan: Plan):
             return jax.lax.fori_loop(stp[s], stp[s + 1], step,
                                      (acc_red, delta, x))
 
-        z = jnp.zeros_like(b_pad)
+        z = jnp.zeros_like(b_in)
         _, _, x = jax.lax.fori_loop(0, n_steps, body, (z, z, z))
         with jax.named_scope("sptrsv.gather"):
-            return jax.lax.psum(x * ops.bcast_trailing(owner_mask, x), AXIS)[:nb]
+            xg = jax.lax.psum(x * ops.bcast_trailing(owner_mask, x), AXIS)[:nb]
+        return _rows_out(xg, b_pad.ndim == 3)
 
     return fn
 
@@ -1259,6 +1294,9 @@ class DistributedSolver:
     invocation, the plan's boundary exchanges (``dispatch_stats``; one per
     superstep for syncfree) and their payload (``comm_bytes_per_solve``
     times the right-hand side's columns); both stay 0 on one device.
+    ``executor.row_panel_solves`` counts the invocations of a levelset-family
+    executor with a panel ``(nb, B, R)`` right-hand side, the calls that run
+    ``(nb+1, R, B)`` row-panel carries; it stays 0 for single vectors.
     """
 
     def __init__(self, plan: Plan, mesh: jax.sharding.Mesh,
@@ -1275,6 +1313,7 @@ class DistributedSolver:
         self._launch_us = reg.histogram("executor.launch_us")
         self._exchanges = reg.counter("executor.exchanges")
         self._exchange_bytes = reg.counter("executor.exchange_bytes")
+        self._row_panel_solves = reg.counter("executor.row_panel_solves")
         self._n_exchanges = (plan.n_supersteps if plan.config.sched == "syncfree"
                              and plan.comm_bytes_per_solve
                              else dispatch_stats(plan)["exchanges"])
@@ -1392,6 +1431,8 @@ class DistributedSolver:
         self._exchanges.inc(self._n_exchanges)
         R = b_blocks.shape[2] if b_blocks.ndim == 3 else 1
         self._exchange_bytes.inc(self._ex_bytes * R)
+        if b_blocks.ndim == 3 and self.plan.config.sched in LEVELSET_SCHEDS:
+            self._row_panel_solves.inc()
         if not isinstance(b_blocks, jax.Array):
             self._h2d_bytes.inc(b_blocks.nbytes)
         with get_tracer().span("sptrsv.launch"):

@@ -26,7 +26,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _gemv_kernel(t_ref, x_ref, o_ref):
-    # t_ref: (1,B,B), x_ref/o_ref: (1,1,B) — the product as a row: x @ T^T
+    # t_ref: (1,B,B), x_ref/o_ref: (1,R,B) — the product as rows: x @ T^T
     o_ref[0] = jax.lax.dot_general(
         x_ref[0], t_ref[0], (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32,
@@ -110,3 +110,25 @@ def block_gemv_grouped(
         interpret=interpret,
     )(tiles, xs)
     return out[:m]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def block_gemm_rows(tiles: jax.Array, xs: jax.Array, *,
+                    interpret: bool = False) -> jax.Array:
+    """Row-panel tile products: tiles (m,B,B) by xs (m,R,B) -> (m,R,B), each
+    panel's rows times the tile's transpose (``x_rows @ T^T``, the
+    :func:`block_gemv` product with R rows). The panels' layout is the
+    levelset executors' ``(nb+1, R, B)`` carry."""
+    m, B, _ = tiles.shape
+    R = xs.shape[1]
+    return pl.pallas_call(
+        _gemv_kernel,
+        grid=(m,),
+        in_specs=[
+            pl.BlockSpec((1, B, B), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, R, B), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, R, B), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, R, B), tiles.dtype),
+        interpret=interpret,
+    )(tiles, xs)

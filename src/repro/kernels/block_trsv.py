@@ -11,8 +11,8 @@ Two in-kernel algorithms:
   steps; this is the §Perf variant (hillclimbed in EXPERIMENTS.md).
 
 Layout: every block's last two dims equal the array's (``(1, B, B)`` tiles,
-``(1, 1, B)`` rhs rows, ``(1, B, R)`` panels), which is what the TPU compiler
-accepts for any B. Rows of a tile are read from the ref at a dynamic sublane
+``(1, 1, B)`` rhs rows, ``(1, B, R)`` or ``(1, R, B)`` panels), which the TPU
+compiler accepts for any B. Tile rows are read from the ref at a dynamic sublane
 offset (``l_ref[0, pl.ds(i, 1), :]``); Mosaic has no ``dynamic_slice`` on
 values.
 """
@@ -151,3 +151,49 @@ def block_trsv(
         interpret=interpret,
     )(diag, rhs.reshape(k, 1, B))
     return out.reshape(k, B)
+
+
+def solve_rows(l_ref, rhs):
+    """Solve ``L X = RHS`` for one ``(B,B)`` lower-triangular tile, with the
+    right-hand sides as the rows of ``rhs`` ``(R,B)``: B steps of masked lane
+    reductions, row ``i`` of L read from the ref at a dynamic sublane. With B
+    on the lanes an ``(8, B)`` panel is one vreg a step, unpadded. Shared by
+    :func:`block_trsm_rows` and the superstep megakernel."""
+    B = l_ref.shape[-1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
+
+    def body(i, x):
+        li = l_ref[pl.ds(i, 1), :]  # (1,B) row i
+        s = jnp.sum(jnp.where(col < i, li * x, 0.0), axis=1, keepdims=True)
+        lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
+        ri = jnp.sum(jnp.where(col == i, rhs, 0.0), axis=1, keepdims=True)
+        return jnp.where(col == i, (ri - s) / lii, x)
+
+    return jax.lax.fori_loop(0, B, body, jnp.zeros_like(rhs))
+
+
+def _trsm_rows_kernel(l_ref, r_ref, x_ref):
+    # l_ref: (1,B,B)  r_ref/x_ref: (1,R,B) — R right-hand sides as rows
+    x_ref[0] = solve_rows(l_ref.at[0], r_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def block_trsm_rows(diag: jax.Array, rhs: jax.Array, *,
+                    interpret: bool = False) -> jax.Array:
+    """Batched row-panel solve: (k,B,B) tiles × (k,R,B) panels -> (k,R,B).
+
+    The panels' layout is the levelset executors' ``(nb+1, R, B)`` carry, so
+    their per-level gathers feed this kernel with no change of layout."""
+    k, B, _ = diag.shape
+    R = rhs.shape[1]
+    return pl.pallas_call(
+        _trsm_rows_kernel,
+        grid=(k,),
+        in_specs=[
+            pl.BlockSpec((1, B, B), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, R, B), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, R, B), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, R, B), diag.dtype),
+        interpret=interpret,
+    )(diag, rhs)

@@ -19,7 +19,9 @@ Backends:
 
 Every op accepts either a single right-hand side per tile (``(k, B)``) or a
 multi-RHS panel (``(k, B, R)``) — the panel path serves R systems from one
-compiled solve (dispatched here by rhs rank).
+compiled solve (dispatched here by rhs rank). The ``*_rows`` ops take the
+panels as ``(k, R, B)`` rows, the layout of the levelset executors' carries,
+with B on the lanes.
 
 Select globally with env ``REPRO_KERNEL_BACKEND`` or per-call with ``backend=``.
 """
@@ -30,8 +32,10 @@ import os
 import jax
 
 from repro.kernels import ref
-from repro.kernels.block_spmv import block_gemm, block_gemv, block_gemv_grouped
-from repro.kernels.block_trsv import block_trsm, block_trsv
+from repro.kernels.block_spmv import (
+    block_gemm, block_gemm_rows, block_gemv, block_gemv_grouped,
+)
+from repro.kernels.block_trsv import block_trsm, block_trsm_rows, block_trsv
 
 BACKENDS = ("reference", "pallas", "fused", "fused_streamed")
 
@@ -116,3 +120,19 @@ def batched_block_gemv(tiles: jax.Array, xs: jax.Array, *, backend: str | None =
     if group > 1:
         return block_gemv_grouped(tiles, xs, group=group, interpret=_interpret())
     return block_gemv(tiles, xs, interpret=_interpret())
+
+
+def batched_block_trsv_rows(diag: jax.Array, rhs: jax.Array, *,
+                            backend: str | None = None) -> jax.Array:
+    """(k,B,B) tiles × (k,R,B) row panels -> (k,R,B)."""
+    if op_backend(backend) == "reference":
+        return ref.block_trsv_rows_ref(diag, rhs)
+    return block_trsm_rows(diag, rhs, interpret=_interpret())
+
+
+def batched_block_gemv_rows(tiles: jax.Array, xs: jax.Array, *,
+                            backend: str | None = None) -> jax.Array:
+    """(m,B,B) tiles × (m,R,B) row panels -> (m,R,B), ``xs[m] @ tiles[m]^T``."""
+    if op_backend(backend) == "reference":
+        return ref.block_gemv_rows_ref(tiles, xs)
+    return block_gemm_rows(tiles, xs, interpret=_interpret())

@@ -34,6 +34,17 @@ def block_gemv_ref(tiles: jax.Array, xs: jax.Array) -> jax.Array:
     return jnp.einsum("mij,mj->mi", tiles, xs, precision=HIGHEST)
 
 
+def block_trsv_rows_ref(diag: jax.Array, rhs: jax.Array) -> jax.Array:
+    """Batched solve of ``(k, R, B)`` row panels: :func:`block_trsv_ref` on
+    the swapped axes."""
+    return jnp.swapaxes(block_trsv_ref(diag, jnp.swapaxes(rhs, 1, 2)), 1, 2)
+
+
+def block_gemv_rows_ref(tiles: jax.Array, xs: jax.Array) -> jax.Array:
+    """Tile products of ``(m, R, B)`` row panels: ``xs[m] @ tiles[m]^T``."""
+    return jnp.einsum("mij,mrj->mri", tiles, xs, precision=HIGHEST)
+
+
 def fused_level_ref(
     diag: jax.Array,  # (k,B,B) diagonal tiles of the wavefront rows
     rhs: jax.Array,  # (k,B)   b - acc for those rows

@@ -83,6 +83,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.block_trsv import solve_rows
+
 N_PREFETCH = 8  # seg, off, wid, stp, sr, ut, trow, tcol
 N_ROW_BUFS = 3  # VMEM row scratch: two operand rows + one result row
 
@@ -124,23 +126,6 @@ def to_rows(v: jax.Array) -> jax.Array:
 def from_rows(v: jax.Array, multi: bool) -> jax.Array:
     """Inverse of :func:`to_rows`."""
     return jnp.swapaxes(v, 1, 2) if multi else v[:, 0, :]
-
-
-def _solve_rows(l_ref, rhs):
-    """Solve ``L X = RHS`` for one ``(B,B)`` lower-triangular tile, with the
-    right-hand sides as the rows of ``rhs`` ``(R,B)``: B steps of masked lane
-    reductions, row ``i`` of L read from the ref at a dynamic sublane."""
-    B = l_ref.shape[-1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-
-    def body(i, x):
-        li = l_ref[pl.ds(i, 1), :]  # (1,B) row i
-        s = jnp.sum(jnp.where(col < i, li * x, 0.0), axis=1, keepdims=True)
-        lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
-        ri = jnp.sum(jnp.where(col == i, rhs, 0.0), axis=1, keepdims=True)
-        return jnp.where(col == i, (ri - s) / lii, x)
-
-    return jax.lax.fori_loop(0, B, body, jnp.zeros_like(rhs))
 
 
 def _superstep_kernel(
@@ -251,7 +236,7 @@ def _superstep_kernel(
                         # an unmerged step delta is exactly +0.0: bit-inert.
                         pltpu.sync_copy(at_row(delta_ref, r), row(1))
                         rhs = rhs - rows[1]
-                    rows[2] = _solve_rows(l_ref, rhs)
+                    rows[2] = solve_rows(l_ref, rhs)
                     pltpu.sync_copy(row(2), at_row(x_ref, r))
 
                 return c

@@ -7,8 +7,8 @@ Three layers over the session API:
   bucket tables) keyed by pattern sha1 x options signature, so short-lived
   workers skip the expensive dependency analysis entirely.
 * :mod:`repro.service.queue` — multi-tenant request admission: same-pattern
-  RHS vectors coalesce into the multi-RHS ``(k, B, R)`` panels the kernels
-  already execute, under a max-wait/max-batch window with per-tenant fairness
+  RHS vectors coalesce into the multi-RHS ``(n, R)`` panels one compiled
+  solve executes, under a max-wait/max-batch window with per-tenant fairness
   and bounded-queue backpressure.
 * :mod:`repro.service.engine` — the serve loop driving one
   :class:`repro.api.SpTRSVContext`: plan-store-backed analyse, in-place value

@@ -6,8 +6,8 @@ one symbolic analysis, and the value fingerprint guarantees every request
 coalesced into one panel solves against identical numeric values (a tenant
 that refreshed its factor lands in a new group rather than silently reading
 another tenant's values). A ready group's RHS vectors are stacked into the
-multi-RHS ``(n, R)`` panel the kernels already execute as ``(k, B, R)``
-tiles, with ``R`` padded up a small static ladder (powers of two up to
+multi-RHS ``(n, R)`` panel, which the levelset executors carry as
+``(nb+1, R, B)`` row panels, with ``R`` padded up a small static ladder (powers of two up to
 ``max_batch``) so a long-lived server compiles at most ``log2(max_batch)+1``
 panel widths per pattern instead of one executor per arrival count.
 

@@ -4,8 +4,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.block_spmv import block_gemm, block_gemv, block_gemv_grouped
-from repro.kernels.block_trsv import block_trsm, block_trsv
+from repro.kernels.block_spmv import (
+    block_gemm, block_gemm_rows, block_gemv, block_gemv_grouped,
+)
+from repro.kernels.block_trsv import block_trsm, block_trsm_rows, block_trsv
 
 
 def _tri(k, B, dtype, seed=0):
@@ -65,12 +67,30 @@ def test_trsv_solves_the_system():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("B,k,R", [(8, 1, 2), (16, 3, 4), (32, 5, 8)])
-def test_trsm_matches_oracle(B, k, R):
+def _panel_cases(cols):
+    """``(k,B,R)`` column-panel cases under their old ids, then ``(k,R,B)``
+    row-panel cases (the levelset executors' carry layout) at the TPU's
+    B = 128 and at 32, for R in 1, 3, 8."""
+    return ([pytest.param(*c, "cols", id="-".join(map(str, c))) for c in cols]
+            + [pytest.param(B, k, R, "rows", id=f"rows-{B}-{k}-{R}")
+               for B in (32, 128) for k, R in ((1, 1), (3, 3), (2, 8))])
+
+
+@pytest.mark.parametrize("B,k,R,layout",
+                         _panel_cases([(8, 1, 2), (16, 3, 4), (32, 5, 8)]))
+def test_trsm_matches_oracle(B, k, R, layout):
     L, _ = _tri(k, B, np.float32, seed=B + R)
     r = jnp.asarray(np.random.default_rng(R).uniform(-1, 1, (k, B, R)).astype(np.float32))
     out = block_trsm(L, r, interpret=True)
     np.testing.assert_allclose(out, ref.block_trsv_ref(L, r), rtol=2e-5, atol=2e-5)
+    if layout == "rows":
+        rows = jnp.swapaxes(r, 1, 2)
+        out_rows = block_trsm_rows(L, rows, interpret=True)
+        assert out_rows.shape == (k, R, B)
+        np.testing.assert_allclose(out_rows, ref.block_trsv_rows_ref(L, rows),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(out_rows, jnp.swapaxes(out, 1, 2),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_trsm_columns_equal_independent_trsv():
@@ -84,13 +104,22 @@ def test_trsm_columns_equal_independent_trsv():
         np.testing.assert_allclose(panel[..., j], single, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("B,m,R", [(8, 1, 2), (16, 7, 4), (32, 4, 5)])
-def test_gemm_matches_oracle(B, m, R):
+@pytest.mark.parametrize("B,m,R,layout",
+                         _panel_cases([(8, 1, 2), (16, 7, 4), (32, 4, 5)]))
+def test_gemm_matches_oracle(B, m, R, layout):
     rng = np.random.default_rng(B + m + R)
     T = jnp.asarray(rng.uniform(-1, 1, (m, B, B)).astype(np.float32))
     x = jnp.asarray(rng.uniform(-1, 1, (m, B, R)).astype(np.float32))
     out = block_gemm(T, x, interpret=True)
     np.testing.assert_allclose(out, ref.block_gemv_ref(T, x), rtol=2e-5, atol=2e-5)
+    if layout == "rows":
+        rows = jnp.swapaxes(x, 1, 2)
+        out_rows = block_gemm_rows(T, rows, interpret=True)
+        assert out_rows.shape == (m, R, B)
+        np.testing.assert_allclose(out_rows, ref.block_gemv_rows_ref(T, rows),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(out_rows, jnp.swapaxes(out, 1, 2),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_ops_dispatch_by_rhs_rank():
@@ -107,3 +136,10 @@ def test_ops_dispatch_by_rhs_rank():
         g2 = ops.batched_block_gemv(L, rp, backend=backend)
         np.testing.assert_allclose(g1, ref.block_gemv_ref(L, r), rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(g2, ref.block_gemv_ref(L, rp), rtol=2e-5, atol=2e-5)
+        # the (k,R,B) row-panel ops, by the same backend rule
+        rows = jnp.swapaxes(rp, 1, 2)
+        t3 = ops.batched_block_trsv_rows(L, rows, backend=backend)
+        g3 = ops.batched_block_gemv_rows(L, rows, backend=backend)
+        assert t3.shape == g3.shape == (3, 4, 16)
+        np.testing.assert_allclose(t3, jnp.swapaxes(out2, 1, 2), rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(g3, jnp.swapaxes(g2, 1, 2), rtol=2e-5, atol=2e-5)

@@ -352,6 +352,43 @@ def test_exchange_counters_follow_the_plan():
     """, devices=4))
 
 
+@pytest.mark.parametrize("comm,sched", [("zerocopy", "levelset"),
+                                        ("unified", "levelset"),
+                                        ("unified", "dagpart")])
+def test_multirhs_panel_matches_columnwise(comm, sched):
+    """On 4 devices, an (n, R) panel through the levelset executors' row-panel
+    carries equals R single-vector solves of the same plan (zero-copy pulls,
+    unified dense exchanges, dagpart's merged supersteps), for R = 3 and 8."""
+    print(run_py(f"""
+        import numpy as np
+        from repro import compat
+        from repro.core import DistributedSolver, SolverConfig, build_plan
+        from repro.core.solver import AXIS
+        from repro.obs.metrics import MetricsRegistry
+        from repro.sparse import suite
+        from repro.sparse.matrix import reference_solve
+
+        a = suite.random_levelled(400, 12, 4.0, seed=7)
+        mesh = compat.make_mesh((4,), (AXIS,))
+        cfg = SolverConfig(block_size=16, comm={comm!r}, sched={sched!r},
+                           partition="malleable")
+        reg = MetricsRegistry()
+        solver = DistributedSolver(build_plan(a, 4, cfg), mesh, registry=reg)
+        assert solver.plan.n_boundary_rows > 0
+        for R in (3, 8):
+            B = np.random.default_rng(R).uniform(-1, 1, (a.n, R))
+            X = solver.solve(B)
+            assert X.shape == (a.n, R)
+            for j in range(R):
+                np.testing.assert_allclose(X[:, j], solver.solve(B[:, j]),
+                                           rtol=2e-5, atol=2e-5)
+                np.testing.assert_allclose(X[:, j], reference_solve(a, B[:, j]),
+                                           rtol=2e-4, atol=2e-4)
+        assert reg.counter("executor.row_panel_solves").value == 2
+        print("OK")
+    """, devices=4))
+
+
 @pytest.mark.slow
 def test_lm_train_step_on_4_device_mesh():
     print(run_py("""

@@ -10,6 +10,7 @@ import pytest
 from strategies import SOLVER_MATRICES as MATRICES, mesh1 as _mesh1
 from repro.core import DistributedSolver, SolverConfig, build_plan, solve_local, sptrsv
 from repro.core.blocking import pad_rhs, unpad_x
+from repro.obs.metrics import MetricsRegistry
 from repro.sparse import suite
 from repro.sparse.matrix import reference_solve
 
@@ -140,19 +141,36 @@ def test_comm_bytes_syncfree_counts_counter_traffic():
     assert lv.n_supersteps == lv.n_levels
 
 
-@pytest.mark.parametrize("sched", ["levelset", "syncfree"])
-def test_multirhs_panel_matches_columnwise(problem, sched):
-    """(n, R) panel through one compiled solve == R independent solves."""
+@pytest.mark.parametrize("sched,R", [
+    pytest.param("levelset", 3, id="levelset"),
+    pytest.param("syncfree", 3, id="syncfree"),
+    pytest.param("levelset", 8, id="levelset-R8"),
+    pytest.param("dagpart", 3, id="dagpart"),
+    pytest.param("dagpart", 8, id="dagpart-R8"),
+])
+def test_multirhs_panel_matches_columnwise(problem, sched, R):
+    """(n, R) panel through one compiled solve == R independent solves. The
+    levelset family carries the panel as (nb+1, R, B) row panels and counts
+    each such call in ``executor.row_panel_solves``; a single vector, and
+    the syncfree executor, count none."""
     a, b, x_ref = problem
     rng = np.random.default_rng(7)
-    B = np.column_stack([b, rng.uniform(-1, 1, (a.n, 2))])
+    B = np.column_stack([b, rng.uniform(-1, 1, (a.n, R - 1))])
     cfg = SolverConfig(block_size=16, sched=sched)
-    solver = DistributedSolver(build_plan(a, 1, cfg), _mesh1())
+    reg = MetricsRegistry()
+    solver = DistributedSolver(build_plan(a, 1, cfg), _mesh1(), registry=reg)
+    np.testing.assert_allclose(solver.solve(b), x_ref, rtol=2e-4, atol=2e-4)
+    assert reg.counter("executor.row_panel_solves").value == 0
     X = solver.solve(B)
-    assert solver.n_solves == 1
+    assert solver.n_solves == 2
+    assert reg.counter("executor.row_panel_solves").value == (sched != "syncfree")
     np.testing.assert_allclose(X[:, 0], x_ref, rtol=2e-4, atol=2e-4)
-    for j in range(1, 3):
+    for j in range(1, R):
         np.testing.assert_allclose(X[:, j], reference_solve(a, B[:, j]),
+                                   rtol=2e-4, atol=2e-4)
+    if sched == "levelset":  # the single-device executor, same row panels
+        xb = solve_local(solver.plan, jnp.asarray(pad_rhs(B, solver.plan.bs)))
+        np.testing.assert_allclose(unpad_x(np.asarray(xb), solver.plan.bs), X,
                                    rtol=2e-4, atol=2e-4)
 
 

@@ -7,17 +7,20 @@ overflow) fails here without one. The topology is described inside a fixture,
 never at import, so every test worker collects the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.core.solver import _bucketize_levels
+from repro import compat
+from repro.core import SolverConfig, build_plan
+from repro.core.solver import AXIS, _bucketize_levels, _levelset_device_fn, device_diag
 from repro.kernels import ops
-from repro.kernels.block_spmv import block_gemm, block_gemv
-from repro.kernels.block_trsv import block_trsm, block_trsv
+from repro.kernels.block_spmv import block_gemm, block_gemm_rows, block_gemv
+from repro.kernels.block_trsv import block_trsm, block_trsm_rows, block_trsv
 from repro.kernels.superstep import superstep_call
 from repro.sparse import suite
 
@@ -79,17 +82,22 @@ def test_plan_widths_are_the_2_20_row_plan(widths):
 
 
 @pytest.mark.parametrize("op,R", [("solve", 1), ("solve", 8),
-                                  ("update", 1), ("update", 8)])
+                                  ("update", 1), ("update", 8),
+                                  ("solve_rows", 8), ("update_rows", 8)])
 def test_per_op_kernel_compiles(one_chip, widths, op, R):
     """The lax.switch executor's batched solve / tile update at the widest
-    level bucket (the TPU's default backend)."""
+    level bucket (the TPU's default backend); ``*_rows`` take the ``(k, R, B)``
+    row panels that the levelset executors carry."""
     B = widths["B"]
-    k = max(widths["sw"] if op == "solve" else widths["uw"])
+    k = max(widths["sw"] if op.startswith("solve") else widths["uw"])
     tiles = jax.ShapeDtypeStruct((k, B, B), jnp.float32, sharding=one_chip)
-    rhs = jax.ShapeDtypeStruct((k, B) if R == 1 else (k, B, R), jnp.float32,
-                               sharding=one_chip)
+    shape = ((k, B) if R == 1 else (k, R, B) if op.endswith("rows")
+             else (k, B, R))
+    rhs = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     fn = {("solve", 1): block_trsv, ("solve", 8): block_trsm,
-          ("update", 1): block_gemv, ("update", 8): block_gemm}[op, R]
+          ("update", 1): block_gemv, ("update", 8): block_gemm,
+          ("solve_rows", 8): block_trsm_rows,
+          ("update_rows", 8): block_gemm_rows}[op, R]
     _compile(lambda t, r: fn(t, r, interpret=False), tiles, rhs)
 
 
@@ -118,3 +126,40 @@ def test_streamed_megakernel_compiles(one_chip, widths, R):
                               solve_widths=w["sw"], upd_widths=w["uw"])
 
     _compile(launch, *args)
+
+
+def _carry_copies(text: str, rows: int) -> list:
+    """Shape and layout of each ``copy`` of a whole ``rows``-row f32 array."""
+    return re.findall(r"= (f32\[%d,[0-9,]+\]\{[^}]*\}) copy\(" % rows, text)
+
+
+def test_levelset_panel_keeps_its_carry_layout(topo, monkeypatch):
+    """The levelset executor's whole program at R=8 on a 512-block-row plan
+    (``grid2d_factor(256)``, B = 128, Pallas kernels): no ``copy`` moves a
+    whole ``(nb+1)``-row carry into another layout, and there are no more
+    whole-carry copies than the R=1 program has. Column panels ``(nb+1, B, R)``
+    were copied between R-on-the-lanes and B-on-the-lanes layouts at every
+    level; ``(nb+1, R, B)`` row panels need no such copy."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # compile, not interpret
+    plan = build_plan(suite.grid2d_factor(256), 1,
+                      SolverConfig(block_size=128, kernel_backend="pallas"))
+    nb, B = plan.bs.nb, plan.bs.B
+    assert nb == 512
+    mesh = compat.make_mesh((1,), (AXIS,), devices=topo.devices[:1])
+    sharded, repl = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    owner = np.ones((1, nb + 1), np.float32)
+    host = (plan.solve_rows, plan.solve_slots, plan.upd_tiles, plan.tile_row,
+            plan.tile_col, plan.tiles, owner, device_diag(plan), plan.ex_rows)
+    args = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded)
+            for x in host[:-1]]
+    args.append(jax.ShapeDtypeStruct(host[-1].shape, host[-1].dtype, sharding=repl))
+    fn = compat.shard_map(_levelset_device_fn(plan), mesh=mesh,
+                          in_specs=(P(AXIS),) * 8 + (P(), P()), out_specs=P())
+    copies = {}
+    for R in (1, 8):
+        shape = (nb + 1, B) if R == 1 else (nb + 1, B, R)
+        b = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=repl)
+        copies[R] = _carry_copies(_compile(fn, *args, b).as_text(), nb + 1)
+    own = f"f32[{nb + 1},8,{B}]{{2,1,0:"  # the row-panel carry, row-major
+    assert all(c.startswith(own) for c in copies[8]), copies[8]
+    assert len(copies[8]) <= len(copies[1]), copies
