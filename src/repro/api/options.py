@@ -73,7 +73,6 @@ class PlanOptions:
     partition: PartitionStrategy = PartitionStrategy.TASKPOOL
     kernel: KernelBackend = KernelBackend.DEFAULT
     tasks_per_device: int = 8
-    gemv_group: int = 0
     rhs_hint: int = 1  # expected RHS panel width, feeds cost model + probes
     # dagpart merge heuristic knobs (see core.partition.merge_levels):
     merge_width: int = 64  # per-device row budget of one merged superstep
@@ -94,8 +93,7 @@ class PlanOptions:
             self, "kernel", _coerce(KernelBackend, self.kernel, "kernel", allow_auto=True)
         )
         for name, lo in (("block_size", 1), ("tasks_per_device", 1),
-                         ("rhs_hint", 1), ("probe_solves", 0), ("gemv_group", 0),
-                         ("merge_width", 1)):
+                         ("rhs_hint", 1), ("probe_solves", 0), ("merge_width", 1)):
             if int(getattr(self, name)) < lo:
                 raise ValueError(f"{name} must be >= {lo}, got {getattr(self, name)}")
         if float(self.merge_cost) < 0:
@@ -128,9 +126,9 @@ class PlanOptions:
         return cls(
             block_size=config.block_size, sched=config.sched, comm=config.comm,
             partition=config.partition, kernel=config.kernel_backend,
-            tasks_per_device=config.tasks_per_device, gemv_group=config.gemv_group,
-            rhs_hint=config.rhs_hint, merge_width=config.merge_width,
-            merge_cost=config.merge_cost, calibrate_cost=config.calibrate_cost,
+            tasks_per_device=config.tasks_per_device, rhs_hint=config.rhs_hint,
+            merge_width=config.merge_width, merge_cost=config.merge_cost,
+            calibrate_cost=config.calibrate_cost,
         )
 
     def to_config(self, *, sched: str | None = None, comm: str | None = None,
@@ -147,9 +145,8 @@ class PlanOptions:
             block_size=self.block_size, comm=comm, sched=sched,
             partition=self.partition.value, tasks_per_device=self.tasks_per_device,
             kernel_backend=None if kernel == KernelBackend.DEFAULT.value else kernel,
-            gemv_group=self.gemv_group, rhs_hint=self.rhs_hint,
-            merge_width=self.merge_width, merge_cost=self.merge_cost,
-            calibrate_cost=self.calibrate_cost,
+            rhs_hint=self.rhs_hint, merge_width=self.merge_width,
+            merge_cost=self.merge_cost, calibrate_cost=self.calibrate_cost,
         )
 
 

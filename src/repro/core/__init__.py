@@ -21,7 +21,6 @@ from repro.core.solver import (
     refresh_plan,
     schedule_table_bytes,
     solve_local,
-    sptrsv,
     step_offsets,
     step_widths,
     stream_dma_bytes_per_solve,

@@ -2,8 +2,9 @@
 
 ``block_row_cost``'s analytic default says a B×B tile product costs 2× the
 diagonal TRSV. This module replaces the guess with a per-backend measurement:
-it compiles one representative block TRSV and block GEMV/GEMM through the
-actual kernel dispatch (``kernels.ops``), runs the loop-aware HLO analysis
+it compiles one representative block TRSV, and a block GEMV on a vector and
+on an ``R_PROBE``-row panel, through the actual kernel dispatch
+(``kernels.ops``), runs the loop-aware HLO analysis
 from :mod:`repro.launch.hlo_cost` over the optimized module, and converts the
 result into the weights of the minimal multi-RHS cost model
 
@@ -96,7 +97,7 @@ def hlo_weights(B: int = 32, backend: str | None = None) -> tuple:
     kb = ops.op_backend(backend)
     diag = jnp.eye(B, dtype=jnp.float32)[None]
     vec = jnp.ones((1, B), jnp.float32)
-    panel = jnp.ones((1, B, R_PROBE), jnp.float32)
+    panel = jnp.ones((1, R_PROBE, B), jnp.float32)  # a (k, R, B) row panel
 
     def trsv(d, r):
         return ops.batched_block_trsv(d, r, backend=kb)
@@ -104,10 +105,13 @@ def hlo_weights(B: int = 32, backend: str | None = None) -> tuple:
     def gemv(t, x):
         return ops.batched_block_gemv(t, x, backend=kb)
 
+    def gemv_rows(t, x):
+        return ops.batched_block_gemv_rows(t, x, backend=kb)
+
     tile_bytes = B * B * 4
     t_f, t_b = _measured(trsv, diag, vec)
     g1_f, g1_b = _measured(gemv, diag, vec)
-    gR_f, gR_b = _measured(gemv, diag, panel)
+    gR_f, gR_b = _measured(gemv_rows, diag, panel)
     # analytic fallbacks: TRSV touches the triangle (B² flops), each product
     # moves the full tile plus in/out vectors
     t1 = _term(t_f, t_b, B * B, tile_bytes + 2 * B * 4)

@@ -52,8 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-import warnings
-
 from repro import compat
 from repro.core.blocking import BlockStructure, build_blocks, refresh_block_values
 from repro.core.partition import (
@@ -100,7 +98,6 @@ class SolverConfig:
     # additionally streams the diag/tile stores from HBM per level (plain
     # "fused" auto-upgrades to streaming above stream_vmem_limit()).
     kernel_backend: str | None = None
-    gemv_group: int = 0
     rhs_hint: int = 1  # expected RHS panel width R, feeds the partition cost model
     calibrate_cost: bool = False  # calibrate cost weights via hlo_cost per backend
     # dagpart merge heuristic knobs (ignored by the other scheds):
@@ -480,15 +477,16 @@ def refresh_plan(plan: Plan, a: CSR) -> Plan:
 # ---------------------------------------------------------------------------
 
 
-def _rows_in(b_pad: jax.Array) -> jax.Array:
-    """The levelset executors' carry layout: a ``(nb+1, B, R)`` panel becomes
-    ``(nb+1, R, B)`` row panels, once, inside the compiled program, with B on
-    the lanes and R on the sublanes; a vector stays as it is."""
+def rows_in(b_pad: jax.Array) -> jax.Array:
+    """The carry layout of every executor and the SpMV: a ``(nb+1, B, R)``
+    panel becomes ``(nb+1, R, B)`` row panels, once, inside the compiled
+    program, with B on the lanes and R on the sublanes; a vector stays as it
+    is."""
     return to_rows(b_pad) if b_pad.ndim == 3 else b_pad
 
 
-def _rows_out(x: jax.Array, multi: bool) -> jax.Array:
-    """Inverse of :func:`_rows_in`."""
+def rows_out(x: jax.Array, multi: bool) -> jax.Array:
+    """Inverse of :func:`rows_in`."""
     return from_rows(x, True) if multi else x
 
 
@@ -508,7 +506,7 @@ def _compact_level_body(
     global max. ``ex is None`` disables the zero-copy boundary pull.
 
     A rank-2 ``b_pad`` and carries are ``(nb+1, B)`` vectors; a rank-3 one
-    holds ``(nb+1, R, B)`` row panels (:func:`_rows_in`), solved and
+    holds ``(nb+1, R, B)`` row panels (:func:`rows_in`), solved and
     multiplied by the row-panel kernels, so no level changes a carry's
     layout.
 
@@ -575,7 +573,6 @@ def _compact_level_body(
                     else:
                         prods = ops.batched_block_gemv(
                             tiles[tids], x[tcol[tids]], backend=cfg.kernel_backend,
-                            group=cfg.gemv_group,
                         )
                     if split_delta:
                         delta = delta.at[trow[tids]].add(prods)
@@ -982,12 +979,12 @@ def solve_local(plan: Plan, b_blocks: jax.Array) -> jax.Array:
     trow = jnp.asarray(plan.tile_row[0])
     tcol = jnp.asarray(plan.tile_col[0])
     tiles = jnp.asarray(plan.tiles[0])
-    b_in = _rows_in(b_pad)
+    b_in = rows_in(b_pad)
     body = _compact_level_body(plan, sr, ss, ut, trow, tcol, tiles, diag, b_in,
                                ex=None)
     acc0 = jnp.zeros_like(b_in)
     _, x = jax.lax.fori_loop(0, plan.n_levels, body, (acc0, acc0))
-    return _rows_out(x[:nb], b_pad.ndim == 3)
+    return rows_out(x[:nb], b_pad.ndim == 3)
 
 
 # ---------------------------------------------------------------------------
@@ -1008,7 +1005,7 @@ def _levelset_device_fn(plan: Plan):
         # leading device dim of sharded operands is 1 inside shard_map
         sr, ss, ut = sr[0], ss[0], ut[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
-        b_in = _rows_in(b_pad)
+        b_in = rows_in(b_pad)
         body = _compact_level_body(
             plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_in,
             ex=ex if has_ex else None,
@@ -1019,7 +1016,7 @@ def _levelset_device_fn(plan: Plan):
             xg = x * ops.bcast_trailing(owner_mask, x)
             if plan.n_devices > 1:
                 xg = jax.lax.psum(xg, AXIS)
-        return _rows_out(xg[:nb], b_pad.ndim == 3)
+        return rows_out(xg[:nb], b_pad.ndim == 3)
 
     return fn
 
@@ -1037,7 +1034,7 @@ def _levelset_unified_device_fn(plan: Plan):
         del ex  # unified ignores the packed exchange schedule
         sr, ss, ut = sr[0], ss[0], ut[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
-        b_in = _rows_in(b_pad)
+        b_in = rows_in(b_pad)
         step = _compact_level_body(
             plan, sr, ss, ut, trow, tcol, tiles, diag[0], b_in, ex=None,
             split_delta=True,
@@ -1058,7 +1055,7 @@ def _levelset_unified_device_fn(plan: Plan):
         _, _, x = jax.lax.fori_loop(0, n_steps, body, (z, z, z))
         with jax.named_scope("sptrsv.gather"):
             xg = jax.lax.psum(x * ops.bcast_trailing(owner_mask, x), AXIS)[:nb]
-        return _rows_out(xg, b_pad.ndim == 3)
+        return rows_out(xg, b_pad.ndim == 3)
 
     return fn
 
@@ -1087,9 +1084,13 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
     ladder is capped by ``plan.frontier_caps`` (a bulk-synchronous sweep
     solves exactly one block level, so the frontier never exceeds the widest
     per-device level).
+
+    A rank-3 ``b_pad`` runs the same sweeps on ``(nb+1, R, B)`` row panels
+    (:func:`rows_in`) with the row-panel kernels, as the levelset executors
+    do.
     """
     cfg = plan.config
-    nb, B = plan.bs.nb, plan.bs.B
+    nb = plan.bs.nb
     zerocopy = cfg.comm == "zerocopy"
     multi = plan.n_devices > 1
     # with no boundary rows every tile's contribution is device-local, so any
@@ -1106,9 +1107,12 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
     def fn(lr, trow, tcol, tiles, owner_mask, diag, indeg, exb, b_pad):
         lr = lr[0]
         trow, tcol, tiles, owner_mask = trow[0], tcol[0], tiles[0], owner_mask[0]
-        me = jax.lax.axis_index(AXIS) if multi else 0
+        panel = b_pad.ndim == 3
+        b_in = rows_in(b_pad)
+        trsv = ops.batched_block_trsv_rows if panel else ops.batched_block_trsv
+        gemv = ops.batched_block_gemv_rows if panel else ops.batched_block_gemv
         ldiag = diag[lr]
-        lb = b_pad[lr]
+        lb = b_in[lr]
         lown = owner_mask[lr] > 0  # valid (non-pad) local rows
         dest_mine = owner_mask[trow] > 0  # tile dest owned by this device
         iota_l = jnp.arange(MLR, dtype=jnp.int32)
@@ -1121,8 +1125,8 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
                 idx = jax.lax.dynamic_slice(order, (0,), (w,))
                 valid = idx < MLR
                 rows = jnp.where(valid, lr[jnp.where(valid, idx, 0)], nb)
-                xs = ops.batched_block_trsv(
-                    diag[rows], b_pad[rows] - acc_red[rows],
+                xs = trsv(
+                    diag[rows], b_in[rows] - acc_red[rows],
                     backend=cfg.kernel_backend,
                 )
                 return x.at[rows].set(
@@ -1138,10 +1142,7 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
                 tid = jnp.where(valid, tid, MLT - 1)  # pad: zero tile, dest nb
                 rd = trow[tid]
                 dmine = dest_mine[tid]
-                prods = ops.batched_block_gemv(
-                    tiles[tid], x[tcol[tid]], backend=cfg.kernel_backend,
-                    group=cfg.gemv_group,
-                )
+                prods = gemv(tiles[tid], x[tcol[tid]], backend=cfg.kernel_backend)
                 pm = jnp.where(ops.bcast_trailing(valid, prods), prods, 0.0)
                 cm = valid.astype(jnp.int32)
                 if needs_ex:
@@ -1198,18 +1199,14 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
             else:
                 # 2. solve the frontier (masked dense over local rows)
                 with jax.named_scope("sptrsv.level_solve"):
-                    xs = ops.batched_block_trsv(
-                        ldiag, lb - acc_red[lr], backend=cfg.kernel_backend
-                    )
+                    xs = trsv(ldiag, lb - acc_red[lr], backend=cfg.kernel_backend)
                     x = x.at[lr].set(
                         jnp.where(ops.bcast_trailing(ready, xs), xs, x[lr]))
                 solved = solved.at[lr].set(jnp.logical_or(solved[lr], ready))
                 # 3. updates from tiles whose source column solved THIS superstep
                 just = jnp.zeros((nb + 1,), jnp.bool_).at[lr].set(ready)
                 tmask = just[tcol]
-                prods = ops.batched_block_gemv(
-                    tiles, x[tcol], backend=cfg.kernel_backend, group=cfg.gemv_group
-                )
+                prods = gemv(tiles, x[tcol], backend=cfg.kernel_backend)
                 pm = jnp.where(ops.bcast_trailing(tmask, prods), prods, 0.0)
                 cm = tmask.astype(jnp.int32)
                 if needs_ex:
@@ -1247,7 +1244,7 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
                 solved=solved, x=x, done=remaining == 0,
             )
 
-        zf = jnp.zeros_like(b_pad)
+        zf = jnp.zeros_like(b_in)
         zi = jnp.zeros((nb + 1,), jnp.int32)
         state = dict(
             acc_red=zf, delta=zf, cnt_red=zi, dcnt=zi,
@@ -1259,7 +1256,7 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
             xg = state["x"] * ops.bcast_trailing(owner_mask, state["x"])
             if multi:
                 xg = jax.lax.psum(xg, AXIS)
-        return xg[:nb]
+        return rows_out(xg[:nb], panel)
 
     return fn
 
@@ -1294,9 +1291,10 @@ class DistributedSolver:
     invocation, the plan's boundary exchanges (``dispatch_stats``; one per
     superstep for syncfree) and their payload (``comm_bytes_per_solve``
     times the right-hand side's columns); both stay 0 on one device.
-    ``executor.row_panel_solves`` counts the invocations of a levelset-family
-    executor with a panel ``(nb, B, R)`` right-hand side, the calls that run
-    ``(nb+1, R, B)`` row-panel carries; it stays 0 for single vectors.
+    ``executor.row_panel_solves`` counts the invocations with a panel
+    ``(nb, B, R)`` right-hand side, the calls that run ``(nb+1, R, B)``
+    row-panel carries (every executor carries panels so); it stays 0 for
+    single vectors.
     """
 
     def __init__(self, plan: Plan, mesh: jax.sharding.Mesh,
@@ -1431,7 +1429,7 @@ class DistributedSolver:
         self._exchanges.inc(self._n_exchanges)
         R = b_blocks.shape[2] if b_blocks.ndim == 3 else 1
         self._exchange_bytes.inc(self._ex_bytes * R)
-        if b_blocks.ndim == 3 and self.plan.config.sched in LEVELSET_SCHEDS:
+        if b_blocks.ndim == 3:
             self._row_panel_solves.inc()
         if not isinstance(b_blocks, jax.Array):
             self._h2d_bytes.inc(b_blocks.nbytes)
@@ -1469,23 +1467,3 @@ class DistributedSolver:
             x = unpad_x(xb, self.plan.bs)
             return x[::-1].copy() if self.plan.transpose else x
 
-
-def sptrsv(
-    a: CSR, b: np.ndarray, *, mesh: jax.sharding.Mesh | None = None,
-    config: SolverConfig | None = None, transpose: bool = False,
-) -> np.ndarray:
-    """Deprecated one-shot API: analyse, plan, solve Lx=b (or L^T x=b).
-
-    Kept as a thin shim over :class:`repro.api.SpTRSVContext` — it re-runs the
-    full analysis on every call, which is exactly the cost the session API
-    amortizes. New code should hold a context and call
-    ``ctx.solve(ctx.analyse(a), b)``.
-    """
-    warnings.warn(
-        "repro.core.sptrsv is deprecated: use repro.api.SpTRSVContext "
-        "(analyse once, factorize/solve many)", DeprecationWarning, stacklevel=2,
-    )
-    from repro.api import SpTRSVContext
-
-    ctx = SpTRSVContext(mesh=mesh, options=config)
-    return ctx.solve(ctx.analyse(a), b, transpose=transpose)

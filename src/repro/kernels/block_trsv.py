@@ -8,10 +8,15 @@ Two in-kernel algorithms:
 * ``row-sweep``  — B scalar steps, each a masked VPU row·x dot (O(B) vector ops).
 * ``panel``      — processes P=8 rows per step: a tiny unrolled P×P triangle
   followed by a rank-P MXU update of the remaining rhs. ~P× fewer sequential
-  steps; this is the §Perf variant (hillclimbed in EXPERIMENTS.md).
+  steps; ``rowsweep`` stays the default until a chip run says otherwise.
 
-Layout: every block's last two dims equal the array's (``(1, B, B)`` tiles,
-``(1, 1, B)`` rhs rows, ``(1, B, R)`` or ``(1, R, B)`` panels), which the TPU
+Two layouts: :func:`block_trsv` solves one ``(k, B)`` vector per tile with
+either algorithm; :func:`block_trsm_rows` solves ``(k, R, B)`` row panels, the
+right-hand sides as rows with B on the lanes (:func:`solve_rows`, shared with
+the superstep megakernel).
+
+Every block's last two dims equal the array's (``(1, B, B)`` tiles,
+``(1, 1, B)`` rhs rows, ``(1, R, B)`` panels), which the TPU
 compiler accepts for any B. Tile rows are read from the ref at a dynamic sublane
 offset (``l_ref[0, pl.ds(i, 1), :]``); Mosaic has no ``dynamic_slice`` on
 values.
@@ -79,46 +84,6 @@ def _trsv_panel_kernel(l_ref, r_ref, x_ref, *, panel: int):
         0, B // P, outer, (r_ref[0], jnp.zeros((1, B), l_ref.dtype))
     )
     x_ref[0] = x
-
-
-def _trsm_rowsweep_kernel(l_ref, r_ref, x_ref):
-    # Multi-RHS row sweep: l_ref (1,B,B), r_ref/x_ref (1,B,R). Same forward
-    # substitution as _trsv_rowsweep_kernel, but the per-row partial dot is a
-    # masked (1,B)@(B,R) matmul — one MXU call amortized over all R systems.
-    B = l_ref.shape[-1]
-    R = r_ref.shape[-1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
-
-    def body(i, x):
-        li = l_ref[0, pl.ds(i, 1), :]  # (1,B) row i
-        s = jnp.dot(
-            jnp.where(col < i, li, 0.0), x, precision=HIGHEST,
-            preferred_element_type=jnp.float32,
-        )  # (1,R) partial dots over the solved prefix, all RHS at once
-        lii = jnp.sum(jnp.where(col == i, li, 0.0), axis=1, keepdims=True)
-        ri = r_ref[0, pl.ds(i, 1), :]  # (1,R)
-        return jnp.where(row == i, (ri - s) / lii, x)
-
-    x_ref[0] = jax.lax.fori_loop(0, B, body, jnp.zeros((B, R), l_ref.dtype))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def block_trsm(diag: jax.Array, rhs: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """Batched multi-RHS solve: (k,B,B) tiles × (k,B,R) panels -> (k,B,R)."""
-    k, B, _ = diag.shape
-    R = rhs.shape[-1]
-    return pl.pallas_call(
-        _trsm_rowsweep_kernel,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, B, B), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, B, R), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, B, R), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, B, R), diag.dtype),
-        interpret=interpret,
-    )(diag, rhs)
 
 
 @functools.partial(jax.jit, static_argnames=("algorithm", "panel", "interpret"))

@@ -17,11 +17,12 @@ Backends:
   ``core.solver.stream_vmem_limit()``. For ``sched="syncfree"`` it behaves
   exactly like ``fused`` (the frontier executor has no resident tile problem).
 
-Every op accepts either a single right-hand side per tile (``(k, B)``) or a
-multi-RHS panel (``(k, B, R)``) — the panel path serves R systems from one
-compiled solve (dispatched here by rhs rank). The ``*_rows`` ops take the
-panels as ``(k, R, B)`` rows, the layout of the levelset executors' carries,
-with B on the lanes.
+Each operation has two forms, one per device layout. ``batched_block_trsv``
+and ``batched_block_gemv`` take a single right-hand side per tile, ``(k, B)``.
+The ``*_rows`` ops take multi-RHS panels as ``(k, R, B)`` row panels, the
+right-hand sides as rows with B on the lanes: the layout every executor and
+the Krylov SpMV carry, so one compiled solve serves R systems. A ``(k, B, R)``
+column panel reaches no kernel; the vector ops refuse rank-3 input.
 
 Select globally with env ``REPRO_KERNEL_BACKEND`` or per-call with ``backend=``.
 """
@@ -32,10 +33,8 @@ import os
 import jax
 
 from repro.kernels import ref
-from repro.kernels.block_spmv import (
-    block_gemm, block_gemm_rows, block_gemv, block_gemv_grouped,
-)
-from repro.kernels.block_trsv import block_trsm, block_trsm_rows, block_trsv
+from repro.kernels.block_spmv import block_gemm_rows, block_gemv
+from repro.kernels.block_trsv import block_trsm_rows, block_trsv
 
 BACKENDS = ("reference", "pallas", "fused", "fused_streamed")
 
@@ -100,25 +99,28 @@ def bcast_trailing(mask: jax.Array, x: jax.Array) -> jax.Array:
     return mask.reshape(mask.shape + (1,) * (x.ndim - mask.ndim))
 
 
+def _vectors_only(x: jax.Array, rows_op: str) -> None:
+    if x.ndim != 2:
+        raise ValueError(
+            f"expected (k, B) vectors, got shape {x.shape}: R-column panels "
+            f"go to ops.{rows_op} as (k, R, B) row panels")
+
+
 def batched_block_trsv(diag: jax.Array, rhs: jax.Array, *, backend: str | None = None,
                        algorithm: str = "rowsweep") -> jax.Array:
-    backend = op_backend(backend)
-    if backend == "reference":
+    """(k,B,B) tiles × (k,B) vectors -> (k,B)."""
+    _vectors_only(rhs, "batched_block_trsv_rows")
+    if op_backend(backend) == "reference":
         return ref.block_trsv_ref(diag, rhs)
-    if rhs.ndim == 3:
-        return block_trsm(diag, rhs, interpret=_interpret())
     return block_trsv(diag, rhs, algorithm=algorithm, interpret=_interpret())
 
 
-def batched_block_gemv(tiles: jax.Array, xs: jax.Array, *, backend: str | None = None,
-                       group: int = 0) -> jax.Array:
-    backend = op_backend(backend)
-    if backend == "reference":
+def batched_block_gemv(tiles: jax.Array, xs: jax.Array, *,
+                       backend: str | None = None) -> jax.Array:
+    """(m,B,B) tiles × (m,B) vectors -> (m,B), ``tiles[m] @ xs[m]``."""
+    _vectors_only(xs, "batched_block_gemv_rows")
+    if op_backend(backend) == "reference":
         return ref.block_gemv_ref(tiles, xs)
-    if xs.ndim == 3:
-        return block_gemm(tiles, xs, interpret=_interpret())
-    if group > 1:
-        return block_gemv_grouped(tiles, xs, group=group, interpret=_interpret())
     return block_gemv(tiles, xs, interpret=_interpret())
 
 

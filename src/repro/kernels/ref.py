@@ -28,9 +28,7 @@ def block_trsv_ref(diag: jax.Array, rhs: jax.Array) -> jax.Array:
 
 
 def block_gemv_ref(tiles: jax.Array, xs: jax.Array) -> jax.Array:
-    """Batched tile*vector: tiles (m,B,B), xs (m,B) or (m,B,R) panels."""
-    if xs.ndim == 3:
-        return jnp.einsum("mij,mjr->mir", tiles, xs, precision=HIGHEST)
+    """Batched tile*vector: tiles (m,B,B), xs (m,B)."""
     return jnp.einsum("mij,mj->mi", tiles, xs, precision=HIGHEST)
 
 

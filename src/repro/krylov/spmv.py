@@ -11,8 +11,9 @@ contributes
 * ``L[r,c]^T @ v[r]``         the mirrored upper entries (scattered to ``c``),
 
 and one psum combines the partial results — the same read-only communication
-model as the solver itself. Multi-RHS panels ``(n, R)`` flow through the same
-compiled matvec via the kernel layer's rank dispatch.
+model as the solver itself. Multi-RHS panels ``(n, R)`` run as
+``(nb+1, R, B)`` row panels on the device, the solver's carry layout, with
+the row-panel kernels.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.core.blocking import pad_rhs, unpad_x
-from repro.core.solver import AXIS, Plan
+from repro.core.solver import AXIS, Plan, rows_in, rows_out
 from repro.kernels import ops
 
 
@@ -44,15 +45,18 @@ def _spmv_device_fn(plan: Plan):
     def fn(tiles, tiles_t, trow, tcol, owner_mask, sym_diag, v_pad):
         tiles, tiles_t = tiles[0], tiles_t[0]
         trow, tcol, owner_mask = trow[0], tcol[0], owner_mask[0]
-        y = ops.batched_block_gemv(sym_diag, v_pad, backend=cfg.kernel_backend)
+        panel = v_pad.ndim == 3
+        gemv = ops.batched_block_gemv_rows if panel else ops.batched_block_gemv
+        v = rows_in(v_pad)
+        y = gemv(sym_diag, v, backend=cfg.kernel_backend)
         y = y * ops.bcast_trailing(owner_mask, y)  # each diag block counted once
-        prods = ops.batched_block_gemv(tiles, v_pad[tcol], backend=cfg.kernel_backend)
+        prods = gemv(tiles, v[tcol], backend=cfg.kernel_backend)
         y = y.at[trow].add(prods)  # pad tiles are zero -> pad adds are inert
-        mirrored = ops.batched_block_gemv(tiles_t, v_pad[trow], backend=cfg.kernel_backend)
+        mirrored = gemv(tiles_t, v[trow], backend=cfg.kernel_backend)
         y = y.at[tcol].add(mirrored)
         if multi:
             y = jax.lax.psum(y, AXIS)
-        return y[:nb]
+        return rows_out(y[:nb], panel)
 
     return fn
 

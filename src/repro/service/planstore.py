@@ -51,7 +51,7 @@ from repro.obs.trace import get_tracer
 from repro.sparse.matrix import CSR
 
 FORMAT = "repro-sptrsv-plan"
-VERSION = 1
+VERSION = 2
 
 # the symbolic (values-free) arrays of a Plan, stored verbatim; diag/tiles
 # and the block structure's numeric tiles are rehydrated via refresh_plan

@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from strategies import mesh1 as _mesh1, random_blocks as _blocks
-from repro.core import SolverConfig, build_plan, sptrsv
+from repro.api import SpTRSVContext
+from repro.core import SolverConfig, build_plan
 from repro.core.blocking import build_blocks
 from repro.core.partition import block_row_cost, cut_stats, make_partition
 from repro.sparse import suite
@@ -103,7 +104,8 @@ def test_strategies_agree_bit_exact_and_match_reference(comm, sched):
     xs = {}
     for part in ("taskpool", "contiguous", "malleable"):
         cfg = SolverConfig(block_size=16, comm=comm, sched=sched, partition=part)
-        xs[part] = sptrsv(a, b, mesh=mesh, config=cfg)
+        ctx = SpTRSVContext(mesh=mesh, options=cfg)
+        xs[part] = ctx.solve(ctx.analyse(a), b)
         np.testing.assert_allclose(xs[part], x_ref, rtol=2e-4, atol=2e-4)
     np.testing.assert_array_equal(xs["taskpool"], xs["contiguous"])
     np.testing.assert_array_equal(xs["taskpool"], xs["malleable"])

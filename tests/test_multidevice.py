@@ -30,7 +30,8 @@ def test_solver_all_modes_on_8_devices():
         import numpy as np, jax
         from repro.sparse import suite
         from repro.sparse.matrix import reference_solve
-        from repro.core import sptrsv, SolverConfig
+        from repro.api import SpTRSVContext
+        from repro.core import SolverConfig
         a = suite.random_levelled(600, 24, 4.0, seed=5)
         b = np.random.default_rng(1).uniform(-1, 1, a.n)
         x_ref = reference_solve(a, b)
@@ -40,7 +41,8 @@ def test_solver_all_modes_on_8_devices():
             for sched in ["levelset", "dagpart", "syncfree"]:
                 for part in ["taskpool", "contiguous", "malleable"]:
                     cfg = SolverConfig(block_size=16, comm=comm, sched=sched, partition=part)
-                    x = sptrsv(a, b, mesh=mesh, config=cfg)
+                    ctx = SpTRSVContext(mesh=mesh, options=cfg)
+                    x = ctx.solve(ctx.analyse(a), b)
                     err = np.abs(x - x_ref).max() / np.abs(x_ref).max()
                     assert err < 1e-5, (comm, sched, part, err)
         print("OK")
@@ -354,11 +356,14 @@ def test_exchange_counters_follow_the_plan():
 
 @pytest.mark.parametrize("comm,sched", [("zerocopy", "levelset"),
                                         ("unified", "levelset"),
-                                        ("unified", "dagpart")])
+                                        ("unified", "dagpart"),
+                                        ("zerocopy", "syncfree"),
+                                        ("unified", "syncfree")])
 def test_multirhs_panel_matches_columnwise(comm, sched):
-    """On 4 devices, an (n, R) panel through the levelset executors' row-panel
-    carries equals R single-vector solves of the same plan (zero-copy pulls,
-    unified dense exchanges, dagpart's merged supersteps), for R = 3 and 8."""
+    """On 4 devices, an (n, R) panel through the executors' row-panel carries
+    equals R single-vector solves of the same plan (zero-copy pulls, unified
+    dense exchanges, dagpart's merged supersteps, syncfree's packed or dense
+    exchange of values and counters), for R = 3 and 8."""
     print(run_py(f"""
         import numpy as np
         from repro import compat
@@ -385,6 +390,37 @@ def test_multirhs_panel_matches_columnwise(comm, sched):
                 np.testing.assert_allclose(X[:, j], reference_solve(a, B[:, j]),
                                            rtol=2e-4, atol=2e-4)
         assert reg.counter("executor.row_panel_solves").value == 2
+        print("OK")
+    """, devices=4))
+
+
+def test_spmv_panel_on_4_devices():
+    """The Krylov SpMV on 4 devices: an (n, R) panel, carried as row panels
+    on the devices, equals the dense ``A @ V`` of the symmetric matrix, and
+    each column equals the single-vector matvec."""
+    print(run_py("""
+        import numpy as np
+        from repro import compat
+        from repro.core import SolverConfig, build_plan
+        from repro.core.solver import AXIS
+        from repro.krylov import spd_lower_from_triangular, symmetric_full_csr
+        from repro.krylov.spmv import DistributedSpMV
+        from repro.sparse import suite
+        from repro.sparse.matrix import to_scipy
+
+        low = spd_lower_from_triangular(suite.grid2d_factor(16, seed=1))
+        plan = build_plan(low, 4, SolverConfig(block_size=8, partition="malleable"))
+        assert plan.n_boundary_rows > 0
+        spmv = DistributedSpMV(plan, compat.make_mesh((4,), (AXIS,)))
+        a = to_scipy(symmetric_full_csr(low)).toarray()
+        for R in (3, 8):
+            V = np.random.default_rng(R).uniform(-1, 1, (low.n, R))
+            Y = spmv.matvec(V)
+            assert Y.shape == (low.n, R)
+            np.testing.assert_allclose(Y, a @ V, rtol=1e-5, atol=1e-5)
+            for j in range(R):
+                np.testing.assert_allclose(Y[:, j], spmv.matvec(V[:, j]),
+                                           rtol=1e-6, atol=1e-6)
         print("OK")
     """, devices=4))
 

@@ -222,6 +222,21 @@ def test_wrong_version_header_rejected(tmp_path):
     assert_falls_back(tmp_path, a, b, opts)
 
 
+def test_version_1_entry_falls_back_to_fresh_analysis(tmp_path):
+    """An entry in the version-1 layout, whose stored config carries a field
+    :class:`SolverConfig` no longer has, is refused at its version header
+    before its config is read: the session re-analyses and solves, and
+    nothing crashes."""
+    a, b = exact_problem()
+    opts = PlanOptions(block_size=16)
+    path = populated_store(tmp_path, a, b, opts)
+    with np.load(path, allow_pickle=False) as z:
+        config = json.loads(str(z["meta"][()]))["config"]
+    rewrite_npz(path, meta_patch={"version": 1,
+                                  "config": dict(config, retired_field=0)})
+    assert_falls_back(tmp_path, a, b, opts)
+
+
 def test_mutated_schedule_table_rejected_by_strict_verifier(tmp_path):
     """A tampered schedule that still parses must die at ``verify_plan``:
     reversing the compacted solve-row order breaks happens-before."""

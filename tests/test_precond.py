@@ -4,7 +4,8 @@ import pytest
 import scipy.sparse.linalg as spla
 
 from repro import compat
-from repro.core import DistributedSolver, SolverConfig, build_plan, sptrsv
+from repro.api import SpTRSVContext
+from repro.core import DistributedSolver, SolverConfig, build_plan
 from repro.krylov.precond import (
     ic0,
     ilu0,
@@ -146,7 +147,8 @@ def test_transpose_solve_matches_scipy(sched):
     a = suite.grid2d_factor(16, seed=2)
     b = np.random.default_rng(3).uniform(-1, 1, a.n)
     cfg = SolverConfig(block_size=16, sched=sched)
-    x = sptrsv(a, b, mesh=_mesh1(), config=cfg, transpose=True)
+    ctx = SpTRSVContext(mesh=_mesh1(), options=cfg)
+    x = ctx.solve(ctx.analyse(a), b, transpose=True)
     x_ref = spla.spsolve_triangular(to_scipy(a).T.tocsr(), b, lower=False)
     np.testing.assert_allclose(x, x_ref, rtol=2e-4, atol=2e-4)
 

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from strategies import SOLVER_MATRICES as MATRICES, mesh1 as _mesh1
-from repro.core import DistributedSolver, SolverConfig, build_plan, solve_local, sptrsv
+from repro.api import SpTRSVContext
+from repro.core import DistributedSolver, SolverConfig, build_plan, solve_local
 from repro.core.blocking import pad_rhs, unpad_x
 from repro.obs.metrics import MetricsRegistry
 from repro.sparse import suite
@@ -27,7 +28,8 @@ def problem(request):
 def test_all_modes_match_reference(problem, comm, sched):
     a, b, x_ref = problem
     cfg = SolverConfig(block_size=16, comm=comm, sched=sched)
-    x = sptrsv(a, b, mesh=_mesh1(), config=cfg)
+    ctx = SpTRSVContext(mesh=_mesh1(), options=cfg)
+    x = ctx.solve(ctx.analyse(a), b)
     np.testing.assert_allclose(x, x_ref, rtol=2e-4, atol=2e-4)
 
 
@@ -42,7 +44,8 @@ def test_solve_local_matches_reference(problem):
 def test_block_size_invariance(block_size):
     a = MATRICES["levelled"]()
     b = np.random.default_rng(1).uniform(-1, 1, a.n)
-    x = sptrsv(a, b, mesh=_mesh1(), config=SolverConfig(block_size=block_size))
+    ctx = SpTRSVContext(mesh=_mesh1(), options=SolverConfig(block_size=block_size))
+    x = ctx.solve(ctx.analyse(a), b)
     np.testing.assert_allclose(x, reference_solve(a, b), rtol=2e-4, atol=2e-4)
 
 
@@ -63,7 +66,8 @@ def test_pallas_backend_end_to_end():
     a = suite.random_levelled(120, 10, 3.0, seed=5)
     b = np.random.default_rng(3).uniform(-1, 1, a.n)
     cfg = SolverConfig(block_size=16, kernel_backend="pallas")
-    x = sptrsv(a, b, mesh=_mesh1(), config=cfg)
+    ctx = SpTRSVContext(mesh=_mesh1(), options=cfg)
+    x = ctx.solve(ctx.analyse(a), b)
     np.testing.assert_allclose(x, reference_solve(a, b), rtol=2e-4, atol=2e-4)
 
 
@@ -149,10 +153,10 @@ def test_comm_bytes_syncfree_counts_counter_traffic():
     pytest.param("dagpart", 8, id="dagpart-R8"),
 ])
 def test_multirhs_panel_matches_columnwise(problem, sched, R):
-    """(n, R) panel through one compiled solve == R independent solves. The
-    levelset family carries the panel as (nb+1, R, B) row panels and counts
-    each such call in ``executor.row_panel_solves``; a single vector, and
-    the syncfree executor, count none."""
+    """(n, R) panel through one compiled solve == R independent solves. Every
+    executor carries the panel as (nb+1, R, B) row panels and counts each
+    such call in ``executor.row_panel_solves``; a single vector counts
+    none."""
     a, b, x_ref = problem
     rng = np.random.default_rng(7)
     B = np.column_stack([b, rng.uniform(-1, 1, (a.n, R - 1))])
@@ -163,7 +167,7 @@ def test_multirhs_panel_matches_columnwise(problem, sched, R):
     assert reg.counter("executor.row_panel_solves").value == 0
     X = solver.solve(B)
     assert solver.n_solves == 2
-    assert reg.counter("executor.row_panel_solves").value == (sched != "syncfree")
+    assert reg.counter("executor.row_panel_solves").value == 1
     np.testing.assert_allclose(X[:, 0], x_ref, rtol=2e-4, atol=2e-4)
     for j in range(1, R):
         np.testing.assert_allclose(X[:, j], reference_solve(a, B[:, j]),
@@ -180,7 +184,8 @@ def test_transpose_solve_all_matrices(problem):
 
     from repro.sparse.matrix import to_scipy
 
-    x = sptrsv(a, b, mesh=_mesh1(), config=SolverConfig(block_size=16), transpose=True)
+    ctx = SpTRSVContext(mesh=_mesh1(), options=SolverConfig(block_size=16))
+    x = ctx.solve(ctx.analyse(a), b, transpose=True)
     x_ref = spla.spsolve_triangular(to_scipy(a).T.tocsr(), b, lower=False)
     np.testing.assert_allclose(x, x_ref, rtol=2e-4, atol=2e-4)
 

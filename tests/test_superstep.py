@@ -13,9 +13,10 @@ import pytest
 
 import strategies
 from strategies import EXACT_MATRICES, dyadic_rhs, mesh1 as _mesh1
+from repro.api import SpTRSVContext
 from repro.core import (
     DistributedSolver, SolverConfig, build_plan, dispatch_stats,
-    fused_segments, solve_local, sptrsv,
+    fused_segments, solve_local,
 )
 from repro.core.blocking import pad_rhs
 from repro.core.solver import _frontier_ladder, level_widths
@@ -83,11 +84,11 @@ def test_solve_local_fused_bit_exact(exact_problem):
 def test_fused_transpose_solve(exact_problem):
     a, b, _ = exact_problem
     mesh = _mesh1()
-    xs = sptrsv(a, b, mesh=mesh, transpose=True,
-                config=SolverConfig(block_size=16, kernel_backend="pallas"))
-    xf = sptrsv(a, b, mesh=mesh, transpose=True,
-                config=SolverConfig(block_size=16, kernel_backend="fused"))
-    np.testing.assert_array_equal(xs, xf)
+    def solve(kb):
+        ctx = SpTRSVContext(mesh=mesh, options=SolverConfig(block_size=16, kernel_backend=kb))
+        return ctx.solve(ctx.analyse(a), b, transpose=True)
+
+    np.testing.assert_array_equal(solve("pallas"), solve("fused"))
 
 
 def test_fused_real_values_match_oracle():
@@ -104,7 +105,8 @@ def test_fused_real_values_match_oracle():
         x_ref = reference_solve(a, b)
         for sched in ("levelset", "syncfree"):
             cfg = SolverConfig(block_size=16, sched=sched, kernel_backend="fused")
-            x = sptrsv(a, b, mesh=mesh, config=cfg)
+            ctx = SpTRSVContext(mesh=mesh, options=cfg)
+            x = ctx.solve(ctx.analyse(a), b)
             np.testing.assert_allclose(x, x_ref, rtol=2e-4, atol=2e-4,
                                        err_msg=f"{name}/{sched}")
 
@@ -147,11 +149,11 @@ def test_streamed_multirhs_bit_exact(exact_problem):
 def test_streamed_transpose_solve(exact_problem):
     a, b, _ = exact_problem
     mesh = _mesh1()
-    xf = sptrsv(a, b, mesh=mesh, transpose=True,
-                config=SolverConfig(block_size=16, kernel_backend="fused"))
-    xs = sptrsv(a, b, mesh=mesh, transpose=True,
-                config=SolverConfig(block_size=16, kernel_backend="fused_streamed"))
-    np.testing.assert_array_equal(xf, xs)
+    def solve(kb):
+        ctx = SpTRSVContext(mesh=mesh, options=SolverConfig(block_size=16, kernel_backend=kb))
+        return ctx.solve(ctx.analyse(a), b, transpose=True)
+
+    np.testing.assert_array_equal(solve("fused"), solve("fused_streamed"))
 
 
 def test_streamed_vmem_buffers_sized_by_max_level_slice():
@@ -267,8 +269,10 @@ def test_frontier_syncfree_agrees_with_dense(exact_problem, comm):
     dense = SolverConfig(block_size=16, sched="syncfree", comm=comm)
     front = SolverConfig(block_size=16, sched="syncfree", comm=comm,
                          kernel_backend="fused")
-    xd = sptrsv(a, b, mesh=mesh, config=dense)
-    xf = sptrsv(a, b, mesh=mesh, config=front)
+    ctx = SpTRSVContext(mesh=mesh, options=dense)
+    xd = ctx.solve(ctx.analyse(a), b)
+    ctx = SpTRSVContext(mesh=mesh, options=front)
+    xf = ctx.solve(ctx.analyse(a), b)
     np.testing.assert_array_equal(xd, xf)
     np.testing.assert_allclose(xf, x_ref, rtol=0, atol=0)
 
@@ -310,10 +314,12 @@ def test_frontier_work_scales_with_bucket_width(monkeypatch):
     cap = plan.frontier_caps[0]
     assert cap < MLR / 4, (cap, MLR)  # premise: skewed levels << local rows
 
-    sptrsv(a, b, mesh=mesh, config=cfg_f)
+    ctx = SpTRSVContext(mesh=mesh, options=cfg_f)
+    ctx.solve(ctx.analyse(a), b)
     frontier_widths = set(recorded)
     recorded.clear()
-    sptrsv(a, b, mesh=mesh, config=SolverConfig(block_size=8, sched="syncfree"))
+    ctx = SpTRSVContext(mesh=mesh, options=SolverConfig(block_size=8, sched="syncfree"))
+    ctx.solve(ctx.analyse(a), b)
     dense_widths = set(recorded)
 
     ladder = set(_frontier_ladder(min(cap, MLR)))

@@ -3,7 +3,8 @@ import numpy as np
 import pytest
 
 from repro import compat
-from repro.core import SolverConfig, sptrsv
+from repro.api import SpTRSVContext
+from repro.core import SolverConfig
 from repro.sparse import suite
 from repro.sparse.matrix import reference_solve
 
@@ -39,8 +40,9 @@ def test_table1_suite_solves_end_to_end():
     for entry in suite.table1_suite(scale=0.02):
         a = entry.build()
         b = rng.uniform(-1, 1, a.n)
-        x = sptrsv(a, b, mesh=_mesh1(),
-                   config=SolverConfig(block_size=16, partition="taskpool"))
+        ctx = SpTRSVContext(mesh=_mesh1(), options=SolverConfig(
+            block_size=16, partition="taskpool"))
+        x = ctx.solve(ctx.analyse(a), b)
         err = np.abs(x - reference_solve(a, b)).max() / max(1e-9, np.abs(x).max())
         assert err < 1e-4, (entry.name, err)
 

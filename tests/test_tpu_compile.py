@@ -19,8 +19,8 @@ from repro import compat
 from repro.core import SolverConfig, build_plan
 from repro.core.solver import AXIS, _bucketize_levels, _levelset_device_fn, device_diag
 from repro.kernels import ops
-from repro.kernels.block_spmv import block_gemm, block_gemm_rows, block_gemv
-from repro.kernels.block_trsv import block_trsm, block_trsm_rows, block_trsv
+from repro.kernels.block_spmv import block_gemm_rows, block_gemv
+from repro.kernels.block_trsv import block_trsm_rows, block_trsv
 from repro.kernels.superstep import superstep_call
 from repro.sparse import suite
 
@@ -81,8 +81,7 @@ def test_plan_widths_are_the_2_20_row_plan(widths):
     assert ops.interpret_mode()  # this process is on the CPU; only the compile targets the chip
 
 
-@pytest.mark.parametrize("op,R", [("solve", 1), ("solve", 8),
-                                  ("update", 1), ("update", 8),
+@pytest.mark.parametrize("op,R", [("solve", 1), ("update", 1),
                                   ("solve_rows", 8), ("update_rows", 8)])
 def test_per_op_kernel_compiles(one_chip, widths, op, R):
     """The lax.switch executor's batched solve / tile update at the widest
@@ -91,11 +90,9 @@ def test_per_op_kernel_compiles(one_chip, widths, op, R):
     B = widths["B"]
     k = max(widths["sw"] if op.startswith("solve") else widths["uw"])
     tiles = jax.ShapeDtypeStruct((k, B, B), jnp.float32, sharding=one_chip)
-    shape = ((k, B) if R == 1 else (k, R, B) if op.endswith("rows")
-             else (k, B, R))
+    shape = (k, B) if R == 1 else (k, R, B)
     rhs = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    fn = {("solve", 1): block_trsv, ("solve", 8): block_trsm,
-          ("update", 1): block_gemv, ("update", 8): block_gemm,
+    fn = {("solve", 1): block_trsv, ("update", 1): block_gemv,
           ("solve_rows", 8): block_trsm_rows,
           ("update_rows", 8): block_gemm_rows}[op, R]
     _compile(lambda t, r: fn(t, r, interpret=False), tiles, rhs)
