@@ -1261,6 +1261,46 @@ def _syncfree_device_fn(plan: Plan, frontier: bool = False):
     return fn
 
 
+def host_order_program(fn, mesh: jax.sharding.Mesh, in_specs: tuple,
+                       nb: int, B: int, transpose: bool):
+    """The compiled program that a numpy right-hand side runs: the executor
+    ``fn``, ``shard_map``ped over ``mesh`` with ``in_specs``, taking its
+    right-hand side and giving its answer flat, in the caller's row-major
+    order.
+
+    Call it as ``program(*plan_args, b_flat, shape=b.shape)`` with ``shape``
+    ``(n,)`` or ``(n, R)``. Inside the program ``b_flat`` is reshaped to
+    ``shape``, reversed for a transpose plan (the plan was built on
+    ``reverse_transpose(a)``), padded to ``nb + 1`` block rows (the last is
+    the executors' pad block) and handed to ``fn``, which lays it out as its
+    carries (:func:`rows_in`); the ``(nb, B[, R])`` answer is unpadded,
+    reversed back and returned flat. A flat array keeps the host's order on
+    the device, so both transfers are straight copies; an ``(nb, B, R)`` or
+    ``(n, R)`` array is laid out with R off the minor dimension there, and
+    each transfer of one is a transpose on the host.
+    """
+
+    def staged(*args, shape):
+        n = shape[0]
+
+        def device_fn(*a):
+            *plan_args, b = a
+            b = b.reshape(shape)
+            if transpose:
+                b = jnp.flip(b, 0)
+            pad = ((0, (nb + 1) * B - n),) + ((0, 0),) * (len(shape) - 1)
+            x = fn(*plan_args, jnp.pad(b, pad).reshape((nb + 1, B) + shape[1:]))
+            x = x.reshape((nb * B,) + shape[1:])[:n]
+            if transpose:
+                x = jnp.flip(x, 0)
+            return x.reshape(-1)
+
+        return compat.shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+                                out_specs=P())(*args)
+
+    return jax.jit(staged, static_argnames="shape")
+
+
 class DistributedSolver:
     """Compiled multi-device SpTRSV for one (matrix, partition, mesh).
 
@@ -1278,23 +1318,34 @@ class DistributedSolver:
     the fused megakernel and the syncfree executor take the whole diagonal,
     replicated.
 
+    A numpy right-hand side (:meth:`solve`) crosses to the device flat, in
+    the caller's order, and the padding, the reversal of a transpose plan
+    and the carry layout happen inside the compiled program
+    (:func:`host_order_program`); :meth:`solve_blocks` takes and gives
+    ``(nb, B[, R])`` device blocks.
+
     Always-on instruments in ``registry`` (default: the process-wide one):
     ``executor.solves`` counts invocations; ``executor.h2d_bytes`` adds, per
-    invocation, the bytes of a right-hand side staged from numpy (a
-    device-resident one counts 0); ``executor.plan_uploads`` counts
-    placements of the plan's arguments and ``executor.plan_upload_bytes``
-    adds their bytes; ``executor.launch_us`` observes the launch phase
-    (concatenate to the return of the compiled call) of calls that run an
-    already-compiled program. The first call of each right-hand-side shape
-    traces and compiles; its cost is ``jit.compile_s``, not launch time.
+    invocation, the bytes of a right-hand side placed from numpy: ``n·R·4``
+    for :meth:`solve`, the blocks' bytes for numpy blocks handed to
+    :meth:`solve_blocks`, 0 for a device-resident one;
+    ``executor.host_order_solves`` counts the :meth:`solve` calls, each
+    staged in host order (never a :meth:`solve_blocks` call);
+    ``executor.plan_uploads`` counts placements of the plan's arguments and
+    ``executor.plan_upload_bytes`` adds their bytes; ``executor.launch_us``
+    observes the launch phase (the compiled call, and for
+    :meth:`solve_blocks` the concatenation of its pad block) of calls that
+    run an already-compiled program. The first call of each right-hand-side
+    shape traces and compiles; its cost is ``jit.compile_s``, not launch
+    time.
     ``executor.exchanges`` and ``executor.exchange_bytes`` add, per
     invocation, the plan's boundary exchanges (``dispatch_stats``; one per
     superstep for syncfree) and their payload (``comm_bytes_per_solve``
     times the right-hand side's columns); both stay 0 on one device.
     ``executor.row_panel_solves`` counts the invocations with a panel
-    ``(nb, B, R)`` right-hand side, the calls that run ``(nb+1, R, B)``
-    row-panel carries (every executor carries panels so); it stays 0 for
-    single vectors.
+    right-hand side (``(n, R)`` numpy or ``(nb, B, R)`` blocks), the calls
+    that run ``(nb+1, R, B)`` row-panel carries (every executor carries
+    panels so); it stays 0 for single vectors.
     """
 
     def __init__(self, plan: Plan, mesh: jax.sharding.Mesh,
@@ -1312,6 +1363,7 @@ class DistributedSolver:
         self._exchanges = reg.counter("executor.exchanges")
         self._exchange_bytes = reg.counter("executor.exchange_bytes")
         self._row_panel_solves = reg.counter("executor.row_panel_solves")
+        self._host_order_solves = reg.counter("executor.host_order_solves")
         self._n_exchanges = (plan.n_supersteps if plan.config.sched == "syncfree"
                              and plan.comm_bytes_per_solve
                              else dispatch_stats(plan)["exchanges"])
@@ -1353,11 +1405,14 @@ class DistributedSolver:
         # one sharding per plan argument; the right-hand side is the last spec
         self._arg_shardings = tuple(
             NamedSharding(mesh, spec) for spec in in_specs[:-1])
+        self._rhs_sharding = NamedSharding(mesh, in_specs[-1])
         self._set_args(plan)
         mapped = compat.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
         )
         self._jitted = jax.jit(mapped)
+        self._host_order = host_order_program(fn, mesh, in_specs, nb,
+                                              plan.bs.B, plan.transpose)
 
     def _set_args(self, plan: Plan) -> None:
         # place the new arguments before dropping the old ones: a result still
@@ -1415,55 +1470,72 @@ class DistributedSolver:
         self._set_args(plan)
 
     def lower(self, R: int = 1) -> "jax.stages.Lowered":
-        """The executor lowered for an R-column right-hand side (``R = 1``:
-        a single vector) — ``.compile().as_text()`` shows what runs."""
-        nb, B = self.plan.bs.nb, self.plan.bs.B
-        shape = (nb + 1, B) if R == 1 else (nb + 1, B, R)
-        return self._jitted.lower(
-            *self._args, jax.ShapeDtypeStruct(shape, jnp.float32))
+        """The program :meth:`solve` runs, lowered for an R-column numpy
+        right-hand side (``R = 1``: a single vector) —
+        ``.compile().as_text()`` shows what runs."""
+        n = self.plan.bs.n
+        shape = (n,) if R == 1 else (n, R)
+        b = jax.ShapeDtypeStruct((n * R,), jnp.float32, sharding=self._rhs_sharding)
+        return self._host_order.lower(*self._args, b, shape=shape)
 
-    def solve_blocks(self, b_blocks: jax.Array) -> jax.Array:
-        """b_blocks: (nb, B) or a multi-RHS panel (nb, B, R) -> same shape."""
+    def _count(self, R: int, panel: bool) -> None:
         self.n_solves += 1
         self._solves.inc()
         self._exchanges.inc(self._n_exchanges)
-        R = b_blocks.shape[2] if b_blocks.ndim == 3 else 1
         self._exchange_bytes.inc(self._ex_bytes * R)
-        if b_blocks.ndim == 3:
+        if panel:
             self._row_panel_solves.inc()
-        if not isinstance(b_blocks, jax.Array):
-            self._h2d_bytes.inc(b_blocks.nbytes)
+
+    def _launch(self, key, call):
+        """``call()`` in the launch phase, timed unless ``key`` compiles."""
         with get_tracer().span("sptrsv.launch"):
             t0 = time.perf_counter_ns()
-            b_pad = jnp.concatenate(
-                [b_blocks, jnp.zeros((1,) + b_blocks.shape[1:], b_blocks.dtype)]
-            )
-            x = self._jitted(*self._args, b_pad)
+            x = call()
             launch_ns = time.perf_counter_ns() - t0
-        key = (b_pad.shape, b_pad.dtype)
         if key in self._compiled:
             self._launch_us.observe(launch_ns / 1e3)
         else:
             self._compiled.add(key)
         return x
 
-    def solve(self, b: np.ndarray) -> np.ndarray:
-        """b: (n,) or (n, R) RHS panel. Transpose plans flip row order at this
-        boundary (the plan was built on ``reverse_transpose(a)``)."""
-        from repro.core.blocking import pad_rhs, unpad_x
+    def solve_blocks(self, b_blocks: jax.Array) -> jax.Array:
+        """b_blocks: (nb, B) or a multi-RHS panel (nb, B, R) -> same shape."""
+        self._count(b_blocks.shape[2] if b_blocks.ndim == 3 else 1,
+                    b_blocks.ndim == 3)
+        if not isinstance(b_blocks, jax.Array):
+            self._h2d_bytes.inc(b_blocks.nbytes)
 
+        def call():
+            b_pad = jnp.concatenate(
+                [b_blocks, jnp.zeros((1,) + b_blocks.shape[1:], b_blocks.dtype)]
+            )
+            return self._jitted(*self._args, b_pad)
+
+        return self._launch((b_blocks.shape, b_blocks.dtype), call)
+
+    def solve(self, b: np.ndarray) -> np.ndarray:
+        """b: (n,) or (n, R) RHS panel, float32 in the caller's row order.
+        It is placed flat, as the host holds it; the compiled program pads
+        it, reverses it for a transpose plan (built on
+        ``reverse_transpose(a)``) and undoes both on the answer, which comes
+        back flat and leaves as a view of ``b``'s shape."""
+        n = self.plan.bs.n
+        b = np.asarray(b)
+        if b.ndim not in (1, 2) or b.shape[0] != n:
+            raise ValueError(f"right-hand side of shape {b.shape}; the plan "
+                             f"solves (n,) or (n, R) with n = {n}")
+        shape = b.shape
+        self._count(shape[1] if b.ndim == 2 else 1, b.ndim == 2)
+        self._host_order_solves.inc()
         tracer = get_tracer()
         with tracer.span("sptrsv.stage_in"):
-            b = np.asarray(b, np.float32)
-            if self.plan.transpose:
-                b = b[::-1]
-            b_host = pad_rhs(b, self.plan.bs)
-            b_blocks = jnp.asarray(b_host)
-        self._h2d_bytes.inc(b_host.nbytes)
-        xb = self.solve_blocks(b_blocks)
+            b_flat = np.ascontiguousarray(b, np.float32).reshape(-1)
+            b_dev = jax.device_put(b_flat, self._rhs_sharding)
+        self._h2d_bytes.inc(b_flat.nbytes)
+        x = self._launch(("host_order", shape), lambda: self._host_order(
+            *self._args, b_dev, shape=shape))
         with tracer.span("sptrsv.fetch"):  # waits for the device
-            xb = np.asarray(xb)
+            x = np.asarray(x)
         with tracer.span("sptrsv.stage_out"):
-            x = unpad_x(xb, self.plan.bs)
-            return x[::-1].copy() if self.plan.transpose else x
+            return x.reshape(shape)
 

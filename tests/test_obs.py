@@ -202,7 +202,7 @@ def test_toggling_tracing_does_not_retrace():
     ctx = SpTRSVContext(mesh=st.mesh1())
     h = ctx.analyse(a)
     ctx.solve(h, b)
-    jitted = ctx.executor(h)._jitted
+    jitted = ctx.executor(h)._host_order  # the program a numpy solve runs
     if not hasattr(jitted, "_cache_size"):
         pytest.skip("jit cache size introspection unavailable")
     size = jitted._cache_size()
@@ -240,9 +240,10 @@ def test_solve_phase_spans_nest_under_solve_in_order(transpose, R):
     assert all(r["dur_us"] <= solve["dur_us"] for r in phases)
 
 
-def test_h2d_bytes_count_host_arguments_and_padded_rhs_across_refresh():
+def test_h2d_bytes_count_host_arguments_and_unpadded_rhs_across_refresh():
     """The plan's arguments are placed once per construction and refresh; a
-    solve stages only its padded right-hand side."""
+    solve places only its right-hand side, unpadded: the pad block is added
+    inside the compiled program."""
     a, b = small_problem()
     reg = met.MetricsRegistry()
     ctx = SpTRSVContext(mesh=st.mesh1(), registry=reg)
@@ -254,7 +255,8 @@ def test_h2d_bytes_count_host_arguments_and_padded_rhs_across_refresh():
     store_bytes = sum(np.asarray(x).nbytes for x in solver._args)
     assert (uploads.value, upload_bytes.value) == (1, store_bytes)
     bs = solver.plan.bs
-    rhs_bytes = bs.nb * bs.B * 4  # the (nb, B) float32 blocks pad_rhs stages
+    rhs_bytes = a.n * 4  # the (n,) float32 right-hand side as the caller holds it
+    assert a.n < bs.nb * bs.B  # so the padded blocks would count more
 
     def per_call():
         before = reg.counter("executor.h2d_bytes").value
@@ -271,6 +273,27 @@ def test_h2d_bytes_count_host_arguments_and_padded_rhs_across_refresh():
     assert per_call() == rhs_bytes
     assert (uploads.value, upload_bytes.value) == (2, 2 * store_bytes)
     assert reg.counter("executor.solves").value == 3
+
+
+def test_host_order_solves_count_numpy_solves_and_no_solve_blocks_call():
+    """Each numpy right-hand side, vector or panel, is one call of the
+    host-order program and places ``n·R·4`` bytes; ``solve_blocks`` calls
+    count in neither the new counter nor, for device blocks, the bytes."""
+    a, b = small_problem()
+    reg = met.MetricsRegistry()
+    solver = DistributedSolver(build_plan(a, 1, PlanOptions().to_config()),
+                               st.mesh1(), registry=reg)
+    host_order = reg.counter("executor.host_order_solves")
+    h2d = reg.counter("executor.h2d_bytes")
+    panel = np.stack([b, 2 * b, -b], axis=1)
+    solver.solve(b)
+    solver.solve(panel)
+    assert (host_order.value, h2d.value) == (2, a.n * 4 + a.n * 3 * 4)
+    solver.solve_blocks(jnp.asarray(pad_rhs(b, solver.plan.bs)))
+    solver.solve_blocks(jnp.asarray(pad_rhs(panel, solver.plan.bs)))
+    assert (host_order.value, h2d.value) == (2, a.n * 16)
+    assert reg.counter("executor.solves").value == 4
+    assert reg.counter("executor.row_panel_solves").value == 2
 
 
 def test_device_resident_argument_drops_out_of_h2d_bytes():

@@ -17,7 +17,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import compat
 from repro.core import SolverConfig, build_plan
-from repro.core.solver import AXIS, _bucketize_levels, _levelset_device_fn, device_diag
+from repro.core.solver import (
+    AXIS, _bucketize_levels, _levelset_device_fn, device_diag,
+    host_order_program, rows_in, rows_out,
+)
 from repro.kernels import ops
 from repro.kernels.block_spmv import block_gemm_rows, block_gemv
 from repro.kernels.block_trsv import block_trsm_rows, block_trsv
@@ -160,3 +163,41 @@ def test_levelset_panel_keeps_its_carry_layout(topo, monkeypatch):
     own = f"f32[{nb + 1},8,{B}]{{2,1,0:"  # the row-panel carry, row-major
     assert all(c.startswith(own) for c in copies[8]), copies[8]
     assert len(copies[8]) <= len(copies[1]), copies
+
+
+def _boundary_layouts(text: str) -> list:
+    """The right-hand side's (last) parameter and the result of the entry
+    computation, each as ``shape{layout}``."""
+    params, result = re.search(r"entry_computation_layout=\{(.*)", text).group(1).split(")->")
+    array = r"[a-z0-9]+\[[0-9,]*\]\{[^}]*\}"
+    return [re.findall(array, params)[-1], re.findall(array, result)[0]]
+
+
+@pytest.mark.parametrize("R", [1, 8])
+def test_numpy_path_boundary_is_in_host_order(topo, widths, R):
+    """The program a numpy solve runs (``host_order_program``) at the 2^20-row
+    widths, around a stand-in executor that solves every diagonal block with
+    the executors' own kernel on their own carries: its right-hand side and
+    its answer are flat and in host order on the device (``{0:T(1024)}``), so
+    each transfer is a straight copy. The ``(nb, B, R)`` panel it replaced
+    was laid out ``{1,2,0}``, and an ``(n, R)`` one would be ``{0,1}``: both
+    transposed on the host at every transfer."""
+    B, nb = widths["B"], widths["nb"]
+    n = nb * B
+    mesh = compat.make_mesh((1,), (AXIS,), devices=topo.devices[:1])
+    repl = NamedSharding(mesh, P())
+
+    def executor(diag, b_pad):
+        rows = rows_in(b_pad)[:nb]
+        solve = block_trsm_rows if R > 1 else block_trsv
+        return rows_out(solve(diag, rows, interpret=False), R > 1)
+
+    program = host_order_program(executor, mesh, (P(), P()), nb, B, transpose=True)
+    diag = jax.ShapeDtypeStruct((nb, B, B), jnp.float32, sharding=repl)
+    b = jax.ShapeDtypeStruct((n * R,), jnp.float32, sharding=repl)
+    shape = (n,) if R == 1 else (n, R)
+    compiled = program.lower(diag, b, shape=shape).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    flat = f"f32[{n * R}]{{0:T(1024)}}"
+    assert _boundary_layouts(text) == [flat, flat]
